@@ -1,6 +1,8 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
+import re
+
 import jax
 
 
@@ -10,6 +12,21 @@ def default_interpret() -> bool:
     interpret=None and resolve it here at call time, so the same code
     path runs on both backends without flags."""
     return jax.default_backend() != "tpu"
+
+
+def compiled_kernels(hlo_text: str) -> set:
+    """Names of the Pallas kernels that a compiled program runs as TPU
+    custom calls, read from `compiled.as_text()`. Each kernel passes its
+    `name` to pallas_call, which tags the call's op_name
+    ".../<name>/pallas_call"; an interpreted kernel leaves no custom
+    call, so it is not counted."""
+    found = set()
+    for line in hlo_text.splitlines():
+        if '"tpu_custom_call"' in line:
+            m = re.search(r'op_name="[^"]*/(\w+)/pallas_call"', line)
+            if m:
+                found.add(m.group(1))
+    return found
 
 
 def resolve_kernel_flag(flag) -> bool:

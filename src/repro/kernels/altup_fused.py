@@ -66,4 +66,5 @@ def altup_predict_correct(x_wide: jax.Array, x_tilde: jax.Array,
         out_specs=pl.BlockSpec((bt, K, bd), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((T, K, d), x_wide.dtype),
         interpret=interpret,
+        name="altup_predict_correct",
     )(x_wide, x_tilde, p, g, sel)

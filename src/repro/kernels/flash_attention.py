@@ -81,8 +81,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale: float, causal: bool,
         v = v_ref[0].astype(jnp.float32)
         if quantized:
             # fused dequant: codes * per-row scale, in VMEM
-            k = k * ks_ref[0][:, None]
-            v = v * vs_ref[0][:, None]
+            k = k * ks_ref[0].T
+            v = v * vs_ref[0].T
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         q_pos = qi * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
@@ -187,7 +187,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             return (bt[b, _logical_j(i, j)], 0, 0)
 
         def scale_map(b, i, j, bt):
-            return (bt[b, _logical_j(i, j)], 0)
+            return (bt[b, _logical_j(i, j)], 0, 0)
 
         q_map = lambda b, i, j, bt: (b, i, 0)
     else:
@@ -196,7 +196,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
         def scale_map(b, i, j):
             # same remap: a skipped kv block skips its scale fetch too
-            return (b, _logical_j(i, j))
+            return (b, 0, _logical_j(i, j))
 
         q_map = lambda b, i, j: (b, i, 0)
 
@@ -207,10 +207,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ]
     operands = [q, k, v]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bk), scale_map),
-                     pl.BlockSpec((1, bk), scale_map)]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        # scales viewed (rows, 1, T): a (1, bk) block of the 2-D (rows, T)
+        # array breaks the TPU lowering's (8, 128) rule, a (1, 1, bk) one
+        # does not; the kernel turns the (1, bk) row back into a column
+        in_specs += [pl.BlockSpec((1, 1, bk), scale_map),
+                     pl.BlockSpec((1, 1, bk), scale_map)]
+        operands += [k_scale.astype(jnp.float32)[:, None],
+                     v_scale.astype(jnp.float32)[:, None]]
 
     scratch_shapes = [
         pltpu.VMEM((bq, 1), jnp.float32),

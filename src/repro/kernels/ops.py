@@ -1,8 +1,10 @@
 """Jitted public wrappers around the Pallas kernels.
 
-These are the entry points the model layers call when `use_pallas` is on
-(TPU); in this CPU container the kernels run under interpret=True and are
-validated against ref.py by the test suite.
+These are the entry points the model layers call. Interpret mode is left
+to the kernel entry points, which resolve `interpret=None` from the
+backend when the wrapper is traced (compiled on TPU, interpreted on
+CPU); nothing here looks at the backend while the module is imported.
+The interpreted kernels are validated against ref.py by the test suite.
 """
 from __future__ import annotations
 
@@ -11,11 +13,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import (altup_fused, default_interpret, flash_attention,
+from repro.kernels import (altup_fused, flash_attention,
                            ragged_decode_attention as ragged_mod,
                            rwkv6_scan)
-
-_INTERPRET = default_interpret()
 
 
 @partial(jax.jit, static_argnames=("block_t", "block_d"))
@@ -36,7 +36,7 @@ def altup_predict_correct(x_wide, x_tilde, sel, p, g, *, block_t=256,
         bd //= 2
     out = altup_fused.altup_predict_correct(
         x_wide.reshape(T, K, d), x_tilde.reshape(T, d), sel, p, g,
-        block_t=bt, block_d=bd, interpret=_INTERPRET)
+        block_t=bt, block_d=bd)
     return out.reshape(*lead, K, d)
 
 
@@ -75,8 +75,7 @@ def ragged_decode_attn(q, k, v, lengths, k_scale=None, v_scale=None, *,
     o = ragged_mod.ragged_decode_attention(qg, k, v, lengths,
                                            k_scale=k_scale,
                                            v_scale=v_scale,
-                                           block_k=block_k,
-                                           interpret=_INTERPRET)
+                                           block_k=block_k)
     return o.reshape(B, 1, H, dh)
 
 
@@ -104,7 +103,7 @@ def paged_ragged_decode_attn(q, k_pool, v_pool, lengths, block_table,
     qg = q[:, 0].reshape(B, Hk, rep, dh)
     o = ragged_mod.paged_ragged_decode_attention(
         qg, k_pool, v_pool, lengths, block_table, page=page, t_max=t_max,
-        k_scale=k_scale, v_scale=v_scale, interpret=_INTERPRET)
+        k_scale=k_scale, v_scale=v_scale)
     return o.reshape(B, 1, H, dh)
 
 
@@ -130,7 +129,7 @@ def mha_flash(q, k, v, k_scale=None, v_scale=None, *, causal=True,
         scales = {"k_scale": folds(k_scale), "v_scale": folds(v_scale)}
     o = flash_attention.flash_attention(
         fold(q), fold(kx), fold(vx), causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=_INTERPRET, **scales)
+        block_q=block_q, block_k=block_k, **scales)
     return o.reshape(B, H, S, dh).transpose(0, 2, 1, 3)
 
 
@@ -175,8 +174,7 @@ def mha_flash_paged(q, k_pool, v_pool, block_table, k_scale=None,
         scales = {"k_scale": pools(k_scale), "v_scale": pools(v_scale)}
     o = flash_attention.flash_attention(
         fold(q), pool(k_pool), pool(v_pool), block_table=btf,
-        causal=causal, window=window, block_q=block_q,
-        interpret=_INTERPRET, **scales)
+        causal=causal, window=window, block_q=block_q, **scales)
     return o.reshape(B, H, S, dh).transpose(0, 2, 1, 3)
 
 
@@ -188,6 +186,6 @@ def rwkv6_wkv(r, k, v, w, u, *, chunk=128):
     fold = lambda t: t.transpose(0, 2, 1, 3).reshape(B * H, S, Dh)
     ub = jnp.broadcast_to(u[None], (B, H, Dh)).reshape(B * H, Dh)
     out, s = rwkv6_scan.rwkv6_wkv(fold(r), fold(k), fold(v), fold(w), ub,
-                                  chunk=chunk, interpret=_INTERPRET)
+                                  chunk=chunk)
     return (out.reshape(B, H, S, Dh).transpose(0, 2, 1, 3),
             s.reshape(B, H, Dh, Dh))

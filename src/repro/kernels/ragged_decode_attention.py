@@ -13,18 +13,25 @@ cache rows every step. This kernel takes per-slot fill depths `lengths`
 * compute for those blocks is predicated off with `pl.when`, so the
   online-softmax accumulators only ever see real rows;
 * the GQA head-group expansion is fused: queries arrive grouped
-  (B, Hk, rep, Dh) and each kv block is read ONCE per kv head and scored
-  against all `rep` grouped queries (a (rep, block_k) MXU matmul), instead
-  of materializing rep copies of k/v like the dense jnp path.
+  (B, Hk, rep, Dh) and each kv row is read ONCE and scored against all
+  `rep` grouped queries of its head (a (rep, block_k) MXU matmul per
+  head), instead of materializing rep copies of k/v like the dense jnp
+  path;
+* the grid is (slot, kv block): one block carries ALL Hk heads,
+  (1, block_k, Hk, Dh) over the (B, T, Hk, Dh) cache, and the heads loop
+  statically in VMEM. The TPU lowering needs the last two block dims
+  divisible by (8, 128) or equal to the array's, so a one-head block
+  (1, block_k, 1, Dh) is refused; all heads keep the cache layout as is.
 
 Quantized slot caches (cfg.kv_cache_dtype = int8 | fp8): k/v arrive as
 1-byte codes with per-row, per-head f32 scales `k_scale`/`v_scale`
-(B, T, Hk) riding along as two extra refs through the SAME clamped index
-map, and dequantization is FUSED into the kv-block load — `code * scale`
-happens in VMEM right before the MXU matmul, so dequantized K/V are never
-materialized in HBM and the cache read shrinks to ~1 byte/elem + 4
-scale bytes per row-head. Block skipping and scalar-prefetch clamping are
-unchanged: a skipped block skips its scale fetch too.
+(B, T, Hk) riding along as two extra refs (blocks (1, block_k, Hk))
+through the SAME clamped index map, and dequantization is FUSED into the
+kv-block load — `code * scale` happens in VMEM right before the MXU
+matmul, so dequantized K/V are never materialized in HBM and the cache
+read shrinks to ~1 byte/elem + 4 scale bytes per row-head. Block
+skipping and scalar-prefetch clamping are unchanged: a skipped block
+skips its scale fetch too.
 
 Ring-buffer sliding-window caches need NO host-side roll and no in-kernel
 position remap: attention is permutation-invariant over the key set once
@@ -65,7 +72,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, *rest, scale: float, block_k: int,
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -75,16 +82,16 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, *rest, scale: float, block_k: int,
 
     length = len_ref[b]
 
-    @pl.when(j * block_k < length)
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32)            # (rep, dh)
-        k = k_ref[0, :, 0].astype(jnp.float32)         # (bk, dh)
-        v = v_ref[0, :, 0].astype(jnp.float32)         # (bk, dh)
+    def head(h):
+        # one kv head of the resident (bk, Hk, dh) block, static h
+        q = q_ref[0, h].astype(jnp.float32)            # (rep, dh)
+        k = k_ref[0, :, h, :].astype(jnp.float32)      # (bk, dh)
+        v = v_ref[0, :, h, :].astype(jnp.float32)      # (bk, dh)
         if quantized:
             # fused dequant: codes * per-row scale, in VMEM — the f32
             # k/v tiles never exist in HBM
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
+            k = k * ks_ref[0, :, h:h + 1]
+            v = v * vs_ref[0, :, h:h + 1]
         # one kv read serves all `rep` grouped queries (fused GQA)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
         kpos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -95,18 +102,30 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, *rest, scale: float, block_k: int,
         rowmask = (j * block_k
                    + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)) < length
         v = jnp.where(rowmask, v, 0.0)
-        m_prev = m_scr[...]                            # (rep, 1)
+        m_prev = m_scr[h]                              # (rep, 1)
         m_new = jnp.maximum(m_prev[:, 0], s.max(axis=-1))
         alpha = jnp.exp(m_prev[:, 0] - m_new)
         pexp = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-        l_scr[:, 0] = alpha * l_scr[:, 0] + pexp.sum(axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot(pexp, v)
-        m_scr[:, 0] = m_new
+        l_scr[h, :, 0] = alpha * l_scr[h, :, 0] + pexp.sum(axis=-1)
+        acc_scr[h] = acc_scr[h] * alpha[:, None] + jax.lax.dot(pexp, v)
+        m_scr[h, :, 0] = m_new
+
+    @pl.when(j * block_k < length)
+    def _body():
+        for h in range(q_ref.shape[1]):
+            head(h)
 
     @pl.when(j == nk - 1)
     def _finish():
-        o_ref[0, 0] = (acc_scr[...]
-                       / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...]
+                    / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _scratch(Hk: int, rep: int, dh: int):
+    # per-head online-softmax state: running max, denominator, accumulator
+    return [pltpu.VMEM((Hk, rep, 1), jnp.float32),
+            pltpu.VMEM((Hk, rep, 1), jnp.float32),
+            pltpu.VMEM((Hk, rep, dh), jnp.float32)]
 
 
 def _paged_kernel(len_ref, bt_ref, *rest, scale: float, block_k: int,
@@ -163,39 +182,35 @@ def paged_ragged_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     lengths = lengths.astype(jnp.int32)
     block_table = block_table.astype(jnp.int32)
 
-    def kv_map(b, h, j, lens, bt):
+    def kv_map(b, j, lens, bt):
         # same clamp as the contiguous kernel, then through the table:
         # past-fill grid steps re-fetch a resident page (elided copy)
         last = jnp.maximum(pl.cdiv(lens[b], page) - 1, 0)
-        return (bt[b, jnp.minimum(j, last)], 0, h, 0)
+        return (bt[b, jnp.minimum(j, last)], 0, 0, 0)
 
-    def scale_map(b, h, j, lens, bt):
+    def scale_map(b, j, lens, bt):
         last = jnp.maximum(pl.cdiv(lens[b], page) - 1, 0)
-        return (bt[b, jnp.minimum(j, last)], 0, h)
+        return (bt[b, jnp.minimum(j, last)], 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, rep, dh), lambda b, h, j, lens, bt: (b, h, 0, 0)),
-        pl.BlockSpec((1, page, 1, dh), kv_map),
-        pl.BlockSpec((1, page, 1, dh), kv_map),
+        pl.BlockSpec((1, Hk, rep, dh), lambda b, j, lens, bt: (b, 0, 0, 0)),
+        pl.BlockSpec((1, page, Hk, dh), kv_map),
+        pl.BlockSpec((1, page, Hk, dh), kv_map),
     ]
     operands = [q, kp, vp]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page, 1), scale_map),
-                     pl.BlockSpec((1, page, 1), scale_map)]
+        in_specs += [pl.BlockSpec((1, page, Hk), scale_map),
+                     pl.BlockSpec((1, page, Hk), scale_map)]
         operands += [k_scale.reshape(n_pages, page, Hk).astype(jnp.float32),
                      v_scale.reshape(n_pages, page, Hk).astype(jnp.float32)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hk, nk),
+        grid=(B, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rep, dh),
-                               lambda b, h, j, lens, bt: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, dh), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, Hk, rep, dh),
+                               lambda b, j, lens, bt: (b, 0, 0, 0)),
+        scratch_shapes=_scratch(Hk, rep, dh),
     )
     kern = functools.partial(_paged_kernel, scale=scale, block_k=page,
                              nk=nk, quantized=quantized)
@@ -204,6 +219,7 @@ def paged_ragged_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hk, rep, dh), q.dtype),
         interpret=interpret,
+        name="paged_ragged_decode_attention",
     )(lengths, block_table, *operands)
 
 
@@ -232,40 +248,36 @@ def ragged_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     nk = pl.cdiv(T, bk)
     lengths = lengths.astype(jnp.int32)
 
-    def kv_map(b, h, j, lens):
+    def kv_map(b, j, lens):
         # clamp to the slot's last needed block: past-fill grid steps
         # re-fetch an already-resident block (elided copy -> no HBM read)
         last = jnp.maximum(pl.cdiv(lens[b], bk) - 1, 0)
-        return (b, jnp.minimum(j, last), h, 0)
+        return (b, jnp.minimum(j, last), 0, 0)
 
-    def scale_map(b, h, j, lens):
+    def scale_map(b, j, lens):
         # same clamp as kv_map: a skipped kv block skips its scales too
         last = jnp.maximum(pl.cdiv(lens[b], bk) - 1, 0)
-        return (b, jnp.minimum(j, last), h)
+        return (b, jnp.minimum(j, last), 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, rep, dh), lambda b, h, j, lens: (b, h, 0, 0)),
-        pl.BlockSpec((1, bk, 1, dh), kv_map),
-        pl.BlockSpec((1, bk, 1, dh), kv_map),
+        pl.BlockSpec((1, Hk, rep, dh), lambda b, j, lens: (b, 0, 0, 0)),
+        pl.BlockSpec((1, bk, Hk, dh), kv_map),
+        pl.BlockSpec((1, bk, Hk, dh), kv_map),
     ]
     operands = [q, k, v]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bk, 1), scale_map),
-                     pl.BlockSpec((1, bk, 1), scale_map)]
+        in_specs += [pl.BlockSpec((1, bk, Hk), scale_map),
+                     pl.BlockSpec((1, bk, Hk), scale_map)]
         operands += [k_scale.astype(jnp.float32),
                      v_scale.astype(jnp.float32)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, Hk, nk),
+        grid=(B, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rep, dh),
-                               lambda b, h, j, lens: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, dh), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, Hk, rep, dh),
+                               lambda b, j, lens: (b, 0, 0, 0)),
+        scratch_shapes=_scratch(Hk, rep, dh),
     )
     kern = functools.partial(_kernel, scale=scale, block_k=bk, nk=nk,
                              quantized=quantized)
@@ -274,4 +286,5 @@ def ragged_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hk, rep, dh), q.dtype),
         interpret=interpret,
+        name="ragged_decode_attention",
     )(lengths, *operands)

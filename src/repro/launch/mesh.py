@@ -8,7 +8,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -22,6 +22,9 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
 
 
 def make_mesh(shape, axes) -> Mesh:
+    """Mesh over the first prod(shape) devices, every axis `Auto`: the
+    models place arrays with sharding constraints and leave propagation
+    to the compiler (jax.make_mesh would default to `Explicit` axes)."""
     n = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) < n:
@@ -29,9 +32,9 @@ def make_mesh(shape, axes) -> Mesh:
             f"need {n} devices, have {len(devices)} — the dry-run must set "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "importing jax")
+    axis_types = (AxisType.Auto,) * len(axes)
     if len(devices) == n:
-        try:
-            return jax.make_mesh(shape, axes)
-        except Exception:
-            pass
-    return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+        # all devices: let jax order them along the physical topology
+        return jax.make_mesh(shape, axes, axis_types=axis_types)
+    return Mesh(np.asarray(devices[:n]).reshape(shape), axes,
+                axis_types=axis_types)

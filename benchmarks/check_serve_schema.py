@@ -29,8 +29,8 @@ TOP_KEYS = (
 )
 RUN_KEYS = ("name", "tokens_per_s", "ms_per_token_p50",
             "ms_per_token_p99", "makespan_s")
-CONTINUOUS_KEYS = RUN_KEYS + ("prefill_s", "decode_s", "prefill_tokens",
-                              "decode_tokens", "fused_steps",
+CONTINUOUS_KEYS = RUN_KEYS + ("prefill_tokens", "decode_tokens",
+                              "fused_steps",
                               "prefix_hits", "hit_rate",
                               "prefill_tokens_saved",
                               "prefill_tokens_saved_frac",

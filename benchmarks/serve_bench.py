@@ -237,8 +237,6 @@ def run_continuous(params, trace, cfg=None, name="continuous", *,
     return {"name": name, "tokens_per_s": total_tokens / span,
             "ms_per_token_p50": p50, "ms_per_token_p99": p99,
             "makespan_s": span,
-            # prefill/decode time split (engine-attributed per fused step)
-            "prefill_s": st["prefill_s"], "decode_s": st["decode_s"],
             "prefill_tokens": st["prefill_tokens"],
             "decode_tokens": st["decode_tokens"],
             "fused_steps": st["steps"],
@@ -298,7 +296,6 @@ def run_speculative_stream(cfg, params, reqs, name, *,
     return {"name": name, "tokens_per_s": total / span,
             "ms_per_token_p50": p50, "ms_per_token_p99": p99,
             "makespan_s": span,
-            "prefill_s": st["prefill_s"], "decode_s": st["decode_s"],
             "prefill_tokens": st["prefill_tokens"],
             "decode_tokens": st["decode_tokens"],
             "fused_steps": st["steps"],
@@ -489,13 +486,11 @@ def run(outdir: str | None = None, n_requests: int = 12) -> List[Dict]:
             paged_row["tokens_per_s"] / ctg_row["tokens_per_s"],
     }
     path = emit_json(payload, "BENCH_serve.json", outdir)
-    pf, dc = ct.get("prefill_s", 0.0), ct.get("decode_s", 0.0)
     hx = payload["host_transfer_bytes_per_step"]
     sp = payload["shared_prefix"]
     print(f"# wrote {path} (continuous/static tokens/s = "
           f"{payload['throughput_speedup']:.2f}x; int8 cache delta = "
-          f"{payload['int8_tokens_per_s_delta']:.2f}x; continuous time "
-          f"split prefill={pf:.3f}s decode={dc:.3f}s; host bytes/step "
+          f"{payload['int8_tokens_per_s_delta']:.2f}x; host bytes/step "
           f"{hx['v1_logits_rows']} -> {hx['v2_sampled_ids']}; shared-"
           f"prefix trace {sp['prefix_speedup']:.2f}x tokens/s at "
           f"hit_rate={sp['hit_rate']:.2f}, "

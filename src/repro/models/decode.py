@@ -897,9 +897,12 @@ def decode_sample_step(params, caches, seen, tokens, pos, n_valid, sparams,
     rows = logits[jnp.arange(B), jnp.maximum(n_valid - 1, 0),
                   :cfg.vocab_size]
     seen = update_seen(seen, tokens, n_valid)
-    ids, lps = sample_rows(rows, sparams, seen,
-                           want_logprobs=want_logprobs,
-                           any_sampled=any_sampled)
+    # the sampler's ops carry the `sample` scope in their op names, so a
+    # profile can sum its device time inside the fused program
+    with jax.named_scope("sample"):
+        ids, lps = sample_rows(rows, sparams, seen,
+                               want_logprobs=want_logprobs,
+                               any_sampled=any_sampled)
     return ids, lps, caches, seen
 
 

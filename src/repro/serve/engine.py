@@ -60,6 +60,7 @@ recurrent (rwkv/mamba) and ring-cache (sliding-window) models.
 """
 from __future__ import annotations
 
+from dataclasses import dataclass, field
 from functools import partial
 from typing import Dict, Iterator, List, Optional, Tuple, Union
 
@@ -82,6 +83,78 @@ from repro.serve.scheduler import SlotScheduler, serve_clock
 from repro.serve.speculative import (AdaptiveK, SpecConfig,
                                      default_draft_layers,
                                      draft_round, spec_verify_step)
+
+
+PHASES = ("admit", "prepare", "dispatch", "wait", "commit")
+
+
+@dataclass
+class StepRecord:
+    """What one Engine.step() did (Engine.last_step).
+
+    width: tokens fed per slot (the chunk width; k + 1 in a speculative
+    round; 0 when no slot was active). pos / nval: host copies of the
+    (B,) positions and fed-token counts the step ran with. events: the
+    step's (rid, token) deltas in generation order. phase_s: seconds
+    spent in each phase of PHASES that the step entered."""
+    width: int = 0
+    kv_len: int = 0
+    active: int = 0
+    admitted: int = 0
+    prefill_tokens: int = 0
+    decode_tokens: int = 0
+    pos: Optional[np.ndarray] = None
+    nval: Optional[np.ndarray] = None
+    events: List[Tuple[int, int]] = field(default_factory=list)
+    phase_s: Dict[str, float] = field(default_factory=dict)
+
+
+class _PhaseClock:
+    """Splits one Engine.step() into consecutive phases. Each phase is a
+    profiler span `engine.<phase>` (jax.profiler.TraceAnnotation, about
+    a microsecond when no profile is being taken) and adds its
+    serve_clock() seconds to stats["<phase>_s"] and the step record."""
+
+    def __init__(self, stats: Dict[str, float], record: StepRecord):
+        self._stats, self._record = stats, record
+        self._name: Optional[str] = None
+
+    def start(self, name: str) -> None:
+        """End the open phase, if any, and open `name`."""
+        self.stop()
+        self._name = name
+        self._span = jax.profiler.TraceAnnotation("engine." + name)
+        self._span.__enter__()
+        self._t = serve_clock()
+
+    def stop(self) -> None:
+        if self._name is None:
+            return
+        dt = serve_clock() - self._t
+        self._span.__exit__(None, None, None)
+        self._stats[self._name + "_s"] += dt
+        self._record.phase_s[self._name] = dt
+        self._name = None
+
+
+def _named(name: str, fn, *args, **kwargs):
+    """partial(fn, *args, **kwargs) called `name`: jax.jit names its
+    program jit_<name>, which profiler traces and compiled-module dumps
+    show (an unnamed partial shows as jit__unknown)."""
+    f = partial(fn, *args, **kwargs)
+    f.__name__ = f.__qualname__ = name
+    return f
+
+
+def clear_seen(seen, slot):
+    """Clear one slot's repetition-penalty row (a cold admission)."""
+    return seen.at[slot].set(False)
+
+
+def seed_seen(seen, slot, ids):
+    """Clear one slot's row, then mark the prefix ids a prefix hit
+    replays (ids padded with the vocabulary size are dropped)."""
+    return seen.at[slot].set(False).at[slot, ids].set(True, mode="drop")
 
 
 def kv_bucket(needed: int, lo: int, cap: int) -> int:
@@ -152,21 +225,21 @@ class Engine:
             speculative = None
         self._spec_cfg: Optional[SpecConfig] = speculative
         self._spec = False          # resolved against the plan lazily
-        self._step = jax.jit(partial(decode_step, cfg=cfg, mesh=mesh),
+        self._step = jax.jit(_named("decode_step", decode_step, cfg=cfg,
+                                    mesh=mesh),
                              static_argnames=("kv_len",))
         # continuous-batching state (allocated lazily on first submit).
         # Paged engines bake the static page size into the fused step;
         # the block table rides in as a traced kwarg each call.
         self._fused = jax.jit(
-            partial(decode_sample_step, cfg=cfg, mesh=mesh,
-                    page_size=(self._page_size if self._paged else 0)),
+            _named("fused_step", decode_sample_step, cfg=cfg, mesh=mesh,
+                   page_size=(self._page_size if self._paged else 0)),
             static_argnames=("kv_len", "want_logprobs", "any_sampled"),
             donate_argnums=(1, 2))
         self._reset = jax.jit(
-            partial(reset_slot, only_recurrent=self._paged),
+            _named("reset_slot", reset_slot, only_recurrent=self._paged),
             donate_argnums=(0,))
-        self._clear_seen = jax.jit(
-            lambda s, slot: s.at[slot].set(False), donate_argnums=(0,))
+        self._clear_seen = jax.jit(clear_seen, donate_argnums=(0,))
         # generate()'s per-token sampling: the SAME sample_rows as the
         # fused serving step, jitted standalone for the static loop
         self._sample = jax.jit(
@@ -177,29 +250,33 @@ class Engine:
         self._seen = None
         self._base_keys: Dict[int, np.ndarray] = {}   # rid -> key data
         self._events: List[Tuple[int, int]] = []      # last step's deltas
+        self.last_step: Optional[StepRecord] = None
         # (B, n) chosen-token logprobs of the most recent generate() run
         # with sampling.logprobs=True; None otherwise
         self.last_logprobs = None
-        # prefill/decode split for benchmarks (benchmarks/serve_bench.py):
-        # step time is attributed proportionally to the tokens each phase
-        # consumed in that fused step. prefix_hits / prefill_tokens_saved
-        # count prefix-cache reuse: saved tokens are prompt tokens that
-        # arrived by slot-to-slot copy instead of being prefilled.
-        # spec_* counters cover the speculative rounds: drafted/accepted
-        # feed the accept rate, spec_k_sum / spec_rounds the mean k
-        # concurrency_peak: most slots active in any one step (the paged
-        # over-commit headline — can exceed the FULL-length request count
-        # the same pool would fit contiguously)
+        # Counters, always on. <phase>_s: seconds spent in each phase of
+        # step() (PHASES; the engine.<phase> profiler spans time the
+        # same intervals). queue_wait_s / admitted: summed admission
+        # minus arrival time of every admitted request, and their count.
+        # prefix_hits / prefill_tokens_saved count prefix-cache reuse:
+        # saved tokens are prompt tokens that arrived by slot-to-slot
+        # copy instead of being prefilled. spec_* counters cover the
+        # speculative rounds: drafted/accepted feed the accept rate,
+        # spec_k_sum / spec_rounds the mean k. concurrency_peak: most
+        # slots active in any one step (the paged over-commit headline —
+        # can exceed the FULL-length request count the same pool would
+        # fit contiguously)
         self.stats = {"steps": 0, "prefill_tokens": 0, "decode_tokens": 0,
-                      "prefill_s": 0.0, "decode_s": 0.0,
+                      **{ph + "_s": 0.0 for ph in PHASES},
+                      "queue_wait_s": 0.0, "admitted": 0,
                       "prefix_hits": 0, "prefill_tokens_saved": 0,
                       "spec_rounds": 0, "spec_drafted": 0,
                       "spec_accepted": 0, "spec_k_sum": 0,
                       "concurrency_peak": 0}
 
     def reset_stats(self) -> None:
-        """Zero the prefill/decode counters (benchmarks call this after
-        their warmup pass so compile time stays out of the split)."""
+        """Zero every counter (benchmarks call this after their warmup
+        pass so compile time stays out of the phase times)."""
         for k in self.stats:
             self.stats[k] = type(self.stats[k])()
 
@@ -310,13 +387,17 @@ class Engine:
             # page-granular jitted helpers; all take fixed-width padded
             # page vectors (_pad_pages) -> one compile each. The gather
             # is read-only (the caches survive a spill), the rest donate.
-            self._copy_pages = jax.jit(partial(copy_pages, page=P),
-                                       donate_argnums=(0,))
-            self._gather_pages = jax.jit(partial(gather_pages, page=P))
-            self._scatter_pages = jax.jit(partial(scatter_pages, page=P),
-                                          donate_argnums=(0,))
-            self._reset_pages = jax.jit(partial(reset_pages, page=P),
-                                        donate_argnums=(0,))
+            self._copy_pages = jax.jit(
+                _named("copy_pages", copy_pages, page=P),
+                donate_argnums=(0,))
+            self._gather_pages = jax.jit(
+                _named("gather_pages", gather_pages, page=P))
+            self._scatter_pages = jax.jit(
+                _named("scatter_pages", scatter_pages, page=P),
+                donate_argnums=(0,))
+            self._reset_pages = jax.jit(
+                _named("reset_pages", reset_pages, page=P),
+                donate_argnums=(0,))
             self._sched = PagedScheduler(
                 self.n_slots, self.max_len, pool=self._pool,
                 prefix_cache=self._prefix_cache,
@@ -340,12 +421,10 @@ class Engine:
         # prefix length, max_len*4 bytes to device per hit — never a
         # (V,)-sized host row on the admission path
         self._copy = jax.jit(
-            partial(copy_prefix, copy_recurrent=self._has_recurrent),
+            _named("copy_prefix", copy_prefix,
+                   copy_recurrent=self._has_recurrent),
             donate_argnums=(0,))
-        self._seed_seen = jax.jit(
-            lambda s, slot, ids: s.at[slot].set(False)
-                                  .at[slot, ids].set(True, mode="drop"),
-            donate_argnums=(0,))
+        self._seed_seen = jax.jit(seed_seen, donate_argnums=(0,))
         # quantized caches also reset at admission: reset_slot zeroes the
         # slot's scale leaves so stale rows dequantize to exact 0 and a
         # NaN/Inf scale from an aborted request cannot survive recycling.
@@ -376,21 +455,23 @@ class Engine:
                 self._spec_has_ring = bool(self._ring_caps)
                 pg = self._page_size if self._paged else 0
                 self._spec_draft = jax.jit(
-                    partial(draft_round, cfg=self.cfg,
-                            draft_layers=D, page_size=pg, mesh=self.mesh),
+                    _named("spec_draft", draft_round, cfg=self.cfg,
+                           draft_layers=D, page_size=pg, mesh=self.mesh),
                     static_argnames=("k", "kv_len", "any_sampled"),
                     donate_argnums=(1,))
                 self._spec_verify = jax.jit(
-                    partial(spec_verify_step, cfg=self.cfg,
-                            page_size=pg, mesh=self.mesh),
+                    _named("spec_verify", spec_verify_step, cfg=self.cfg,
+                           page_size=pg, mesh=self.mesh),
                     static_argnames=("kv_len", "want_logprobs",
                                      "any_sampled"),
                     donate_argnums=(1, 2))
                 self._spec_snap = jax.jit(
-                    partial(snapshot_rows, self.cfg, page=pg),
+                    _named("spec_snapshot", snapshot_rows, self.cfg,
+                           page=pg),
                     static_argnames=("S",))
                 self._spec_restore = jax.jit(
-                    partial(restore_rows, self.cfg, page=pg),
+                    _named("spec_restore", restore_rows, self.cfg,
+                           page=pg),
                     static_argnames=("S",), donate_argnums=(0,))
                 self._spec = True
         if self._paged:
@@ -423,8 +504,8 @@ class Engine:
             # the contiguous layout.
             if not self._paged:
                 self._copy = jax.jit(
-                    partial(copy_prefix,
-                            copy_recurrent=self._has_recurrent),
+                    _named("copy_prefix", copy_prefix,
+                           copy_recurrent=self._has_recurrent),
                     donate_argnums=(0,),
                     out_shardings=prefix_copy_shardings(self.cfg, caches,
                                                         self.mesh))
@@ -455,11 +536,26 @@ class Engine:
         """One fused step: admit queued requests into free slots, advance
         every active slot (a chunk of prompt tokens while prefilling, one
         token while decoding), retire finished requests. The (rid, token)
-        deltas sampled this step are exposed via stream().
+        deltas sampled this step are exposed via stream() and, with the
+        step's shape and phase times, via last_step.
         Returns the number of slots that were active this step."""
         if self._sched is None:
             return 0
-        for st in self._sched.admit():
+        rec = self.last_step = StepRecord()
+        self._events = rec.events
+        clock = _PhaseClock(self.stats, rec)
+        with jax.profiler.TraceAnnotation("engine.step"):
+            try:
+                return self._run_step(clock, rec)
+            finally:
+                clock.stop()
+
+    def _admit(self) -> int:
+        """Admit queued requests and run their admission programs (slot
+        resets, prefix and page copies, seen-row seeding). Returns how
+        many were admitted."""
+        admitted = self._sched.admit()
+        for st in admitted:
             hit = st.prefix_len > 0
             if self._paged:
                 # paged admission actions (serve/paging.py), in order:
@@ -523,10 +619,22 @@ class Engine:
                 self._seen = self._clear_seen(self._seen,
                                               np.int32(st.slot))
             self._sched.release_donor(st)
+            self.stats["queue_wait_s"] += st.t_admit - st.request.arrival
+        self.stats["admitted"] += len(admitted)
+        return len(admitted)
+
+    def _run_step(self, clock: _PhaseClock, rec: StepRecord) -> int:
+        """step()'s body, phase by phase: admit, prepare (the step's
+        inputs, put on the device), dispatch (the jitted call), wait (the
+        host blocked on the sampled ids), commit (advance, note tokens,
+        retire)."""
+        clock.start("admit")
+        rec.admitted = self._admit()
         active = dict(self._sched.active)
-        self._events = []
         if not active:
             return 0
+        clock.start("prepare")
+        rec.active = len(active)
         self.stats["concurrency_peak"] = max(
             self.stats["concurrency_peak"], len(active))
         if self._paged:
@@ -540,7 +648,7 @@ class Engine:
         # fused path. A degenerate round (every slot at its last token)
         # also falls through.
         if self._spec and not any(st.in_prefill for st in active.values()):
-            n = self._spec_round(active)
+            n = self._spec_round(active, clock, rec)
             if n is not None:
                 return n
         B = self.n_slots
@@ -578,26 +686,27 @@ class Engine:
         sp_dev = {k: jnp.asarray(v) for k, v in sparams.items()}
         if self._sp_shardings is not None:
             sp_dev = jax.device_put(sp_dev, self._sp_shardings)
-        t0 = serve_clock()
         pkw = {"block_table": self._bt} if self._paged else {}
+        args = (jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(nval),
+                sp_dev)
+        rec.width, rec.kv_len, rec.pos, rec.nval = C, kv_len, pos, nval
+        rec.prefill_tokens, rec.decode_tokens = pf_tokens, dec_tokens
+        clock.start("dispatch")
         ids, lps, self._caches, self._seen = self._fused(
-            self.params, self._caches, self._seen, jnp.asarray(tokens),
-            jnp.asarray(pos), jnp.asarray(nval), sp_dev,
+            self.params, self._caches, self._seen, *args,
             kv_len=kv_len, want_logprobs=want_lp,
             any_sampled=any_sampled, **pkw)
+        clock.start("wait")
         ids = np.asarray(ids)                 # (B,) — the only per-step
         lps = np.asarray(lps) if want_lp else None  # device->host pulls
+        clock.start("commit")
         # ONE clock (serve_clock) for step timing AND token timestamps:
         # Completion.ttft_s/latency_s are differences against Request
         # .arrival on the same monotonic base, so they cannot go negative
         now = serve_clock()
-        dt = now - t0
-        total = max(pf_tokens + dec_tokens, 1)
         self.stats["steps"] += 1
         self.stats["prefill_tokens"] += pf_tokens
         self.stats["decode_tokens"] += dec_tokens
-        self.stats["prefill_s"] += dt * pf_tokens / total
-        self.stats["decode_s"] += dt * dec_tokens / total
         for slot, st in active.items():
             st.advance(int(nval[slot]))
             if not samples[slot]:
@@ -613,12 +722,14 @@ class Engine:
                 self._base_keys.pop(st.request.rid, None)
         return len(active)
 
-    def _spec_round(self, active) -> Optional[int]:
+    def _spec_round(self, active, clock: _PhaseClock,
+                    rec: StepRecord) -> Optional[int]:
         """One speculative draft/verify/rollback round (the step() body
-        when speculative mode is on and every active slot is decoding).
-        Returns the active-slot count, or None when the round would be
-        degenerate (every slot's per-slot draft budget is 0) — the
-        caller then falls through to the normal fused step."""
+        when speculative mode is on and every active slot is decoding),
+        entered in the prepare phase. Returns the active-slot count, or
+        None when the round would be degenerate (every slot's per-slot
+        draft budget is 0) — the caller then falls through to the normal
+        fused step."""
         B = self.n_slots
         # per-slot draft budget: the controller's k, clamped so the round
         # cannot overrun max_new (a round commits <= k_b + 1 tokens) or
@@ -656,8 +767,11 @@ class Engine:
         if self._sp_shardings is not None:
             sp_dev = jax.device_put(sp_dev, self._sp_shardings)
         pos_dev = jnp.asarray(pos)
+        tok_dev = jnp.asarray(tokens[:, :1])
+        caps_dev, nval_dev = jnp.asarray(caps_arr), jnp.asarray(nval)
         pkw = {"block_table": self._bt} if self._paged else {}
-        t0 = serve_clock()
+        rec.width, rec.kv_len, rec.pos, rec.nval = S, kv_len, pos, nval
+        clock.start("dispatch")
         # 0. snapshot the ring rows this round will touch (codes+scales)
         snap = None
         if self._spec_has_ring:
@@ -668,9 +782,9 @@ class Engine:
         # repetition-penalty table); self._seen itself is not donated
         # here, so its buffer survives for the verify step.
         tok_mat, q_mat, caches, _ = self._spec_draft(
-            self.params, self._caches, self._seen,
-            jnp.asarray(tokens[:, :1]), pos_dev, jnp.asarray(caps_arr),
-            sp_dev, k=k, kv_len=kv_len, any_sampled=any_sampled, **pkw)
+            self.params, self._caches, self._seen, tok_dev, pos_dev,
+            caps_dev, sp_dev, k=k, kv_len=kv_len, any_sampled=any_sampled,
+            **pkw)
         # 2. undo the draft's ring writes BEFORE verify: the chunk reads
         # the pre-round window (read-before-write path in decode_attn)
         if self._spec_has_ring:
@@ -679,14 +793,15 @@ class Engine:
                 **pkw)
         # 3. fused chunk verify + on-device acceptance
         committed, n_comm, lps, caches, self._seen = self._spec_verify(
-            self.params, caches, self._seen, tok_mat, pos_dev,
-            jnp.asarray(nval), sp_dev, q_mat, kv_len=kv_len,
-            want_logprobs=want_lp, any_sampled=any_sampled, **pkw)
+            self.params, caches, self._seen, tok_mat, pos_dev, nval_dev,
+            sp_dev, q_mat, kv_len=kv_len, want_logprobs=want_lp,
+            any_sampled=any_sampled, **pkw)
+        clock.start("wait")
         comm_np = np.asarray(committed)
         nc_np = np.asarray(n_comm)
         lps_np = np.asarray(lps) if want_lp else None
+        clock.start("commit")
         now = serve_clock()
-        dt = now - t0
         # 4. host commit: per-rid deltas strictly in generation order,
         # finish reasons re-checked token-by-token so eos/stop can
         # truncate a round's tail mid-commit
@@ -721,9 +836,9 @@ class Engine:
                                         jnp.asarray(starts), S=S, **pkw)
         self._caches = caches
         self._spec_k.update(accepted_total, drafted_total)
+        rec.decode_tokens = committed_total
         self.stats["steps"] += 1
         self.stats["decode_tokens"] += committed_total
-        self.stats["decode_s"] += dt
         self.stats["spec_rounds"] += 1
         self.stats["spec_drafted"] += drafted_total
         self.stats["spec_accepted"] += accepted_total
@@ -752,7 +867,8 @@ class Engine:
             prefix_len=st.prefix_len,
             logprobs=(tuple(st.logprobs) if r.sampling.logprobs
                       else None),
-            submitted_at=r.arrival, first_token_at=st.t_first,
+            submitted_at=r.arrival, admitted_at=st.t_admit,
+            first_token_at=st.t_first,
             finished_at=st.t_done)
 
     def collect(self, rid: Optional[int] = None):

@@ -303,7 +303,7 @@ class PagedScheduler(SlotScheduler):
             fresh = pool.allocate(slot, alias=alias,
                                   n_fresh=need - len(alias))
             slot_pages = pool.slot_pages[slot]
-            st = RequestState(request=req, slot=slot)
+            st = RequestState(request=req, slot=slot, t_admit=serve_clock())
             st.paged = {"fresh": fresh, "alias": len(alias)}
             # a hit only counts if the prefix rows are actually
             # reachable (aliased, copyable, or restorable from a blob)

@@ -128,6 +128,7 @@ class Completion:
     prefix_len: int = 0
     logprobs: Optional[Tuple[float, ...]] = None
     submitted_at: float = 0.0
+    admitted_at: float = 0.0
     first_token_at: float = 0.0
     finished_at: float = 0.0
 

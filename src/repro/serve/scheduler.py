@@ -53,7 +53,7 @@ from repro.serve.sampling import SamplingParams, finish_reason_for
 
 def serve_clock() -> float:
     """THE serving clock. Every serving timestamp — Request.arrival,
-    RequestState.t_first/t_done, the engine's step timing — reads this
+    RequestState.t_admit/t_first/t_done, the engine's step timing — reads this
     one monotonic clock, so Completion.ttft_s/latency_s are differences
     on a single time base and can never go negative from clock mixing
     (time.monotonic and time.perf_counter have unrelated epochs)."""
@@ -87,6 +87,7 @@ class RequestState:
     generated: List[int] = field(default_factory=list)
     logprobs: List[float] = field(default_factory=list)
     finish_reason: Optional[str] = None
+    t_admit: float = 0.0            # admission into a slot (serve_clock)
     t_first: float = 0.0            # first sampled token (serve_clock)
     t_done: float = 0.0             # retirement (serve_clock)
     prefix_len: int = 0             # cache rows inherited from a donor
@@ -392,7 +393,7 @@ class SlotScheduler:
                     donor.refcount -= 1
                 break
             self._queue.popleft()
-            st = RequestState(request=req, slot=slot)
+            st = RequestState(request=req, slot=slot, t_admit=serve_clock())
             if donor is not None:
                 st.prefix_len, st.prefix_src = p, donor.slot
                 st.pos = st.cursor = p

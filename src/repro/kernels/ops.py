@@ -54,26 +54,28 @@ def decode_altup_predict_correct(x_wide, x_tilde, sel, p, g):
                                  block_t=min(64, B), block_d=512)
 
 
-@partial(jax.jit, static_argnames=("block_k",))
+@partial(jax.jit, static_argnames=("t_max", "block_k"))
 def ragged_decode_attn(q, k, v, lengths, k_scale=None, v_scale=None, *,
-                       block_k=128):
+                       layer=None, t_max=None, block_k=128):
     """Length-aware S=1 GQA decode attention over slot caches.
 
     q: (B, 1, H, dh) single-token queries; k, v: (B, T, Hk, dh) slot
-    caches; lengths: (B,) per-slot valid-row counts. Heads are grouped
-    (B, Hk, rep, dh) — matching sdpa's GQA layout — so each cache row is
-    read once per kv head, not once per query head. k_scale/v_scale:
-    optional (B, T, Hk) f32 per-row-head scales for quantized (int8/fp8)
-    slot caches — dequant fuses into the kv-block load inside the kernel.
-    Returns (B, 1, H, dh).
+    caches, or a segment's stacked (n, B, T, Hk, dh) caches read at the
+    int32 `layer`; lengths: (B,) per-slot valid-row counts. Heads are
+    grouped (B, Hk, rep, dh) — matching sdpa's GQA layout — so each cache
+    row is read once per kv head, not once per query head.
+    k_scale/v_scale: optional (B, T, Hk) | (n, B, T, Hk) f32 per-row-head
+    scales for quantized (int8/fp8) slot caches — dequant fuses into the
+    kv-block load inside the kernel. t_max: static read bound (the kv
+    bucket; default T). Returns (B, 1, H, dh).
     """
     B, S, H, dh = q.shape
     assert S == 1, "ragged decode kernel is single-token (S=1) only"
-    Hk = k.shape[2]
+    Hk = k.shape[-2]
     rep = H // Hk
     qg = q[:, 0].reshape(B, Hk, rep, dh)
-    o = ragged_mod.ragged_decode_attention(qg, k, v, lengths,
-                                           k_scale=k_scale,
+    o = ragged_mod.ragged_decode_attention(qg, k, v, lengths, layer=layer,
+                                           t_max=t_max, k_scale=k_scale,
                                            v_scale=v_scale,
                                            block_k=block_k)
     return o.reshape(B, 1, H, dh)
@@ -82,28 +84,29 @@ def ragged_decode_attn(q, k, v, lengths, k_scale=None, v_scale=None, *,
 @partial(jax.jit, static_argnames=("page", "t_max", "block_k"))
 def paged_ragged_decode_attn(q, k_pool, v_pool, lengths, block_table,
                              k_scale=None, v_scale=None, *, page, t_max,
-                             block_k=None):
+                             layer=None, block_k=None):
     """Paged-pool variant of `ragged_decode_attn`.
 
     q: (B, 1, H, dh) single-token queries; k_pool/v_pool: (R, Hk, dh)
-    batchless row pools (R = n_pages * page); block_table: (B, npages)
-    int32 physical-page ids per logical page; lengths: (B,) fill depths.
-    k_scale/v_scale: optional (R, Hk) f32 pool scales (quantized caches).
-    t_max: static logical read bound (the kv bucket). The kernel indexes
-    KV pages through the block table in its scalar-prefetch index map —
-    no gathered copy of the cache is ever materialized. block_k is
-    accepted for signature parity and ignored (the page is the block).
-    Returns (B, 1, H, dh).
+    batchless row pools (R = n_pages * page), or a segment's stacked
+    (n, R, Hk, dh) pools read at the int32 `layer`; block_table:
+    (B, npages) int32 physical-page ids per logical page; lengths: (B,)
+    fill depths. k_scale/v_scale: optional (R, Hk) | (n, R, Hk) f32 pool
+    scales (quantized caches). t_max: static logical read bound (the kv
+    bucket). The kernel indexes KV pages through the block table in its
+    scalar-prefetch index map — no gathered copy of the cache is ever
+    materialized. block_k is accepted for signature parity and ignored
+    (the page is the block). Returns (B, 1, H, dh).
     """
     del block_k
     B, S, H, dh = q.shape
     assert S == 1, "paged ragged decode kernel is single-token (S=1) only"
-    Hk = k_pool.shape[1]
+    Hk = k_pool.shape[-2]
     rep = H // Hk
     qg = q[:, 0].reshape(B, Hk, rep, dh)
     o = ragged_mod.paged_ragged_decode_attention(
         qg, k_pool, v_pool, lengths, block_table, page=page, t_max=t_max,
-        k_scale=k_scale, v_scale=v_scale)
+        layer=layer, k_scale=k_scale, v_scale=v_scale)
     return o.reshape(B, 1, H, dh)
 
 

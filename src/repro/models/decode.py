@@ -2,7 +2,11 @@
 
 Cache layout mirrors the segment plan: one pytree per segment, stacked over
 the segment's layers so the decode layer loop is the same lax.scan as
-training (cache slices ride along as scan xs, updated slices come out as ys).
+training. KV caches (attn, mla) ride in the scan CARRY with the layer
+index: each layer writes only its new rows into the stack and reads its
+layer where it lies, so a step moves the new rows and the rows it reads,
+never a per-layer slice or a rebuilt copy of the stack. Recurrent state
+(rwkv, mamba) is rewritten whole every step and rides as scan xs/ys.
 
 Cache kinds:
   attn : k, v      (n, B, T, Hk, Dh)   post-RoPE keys
@@ -357,8 +361,9 @@ def _nb(mesh) -> int:
     return n
 
 
-def _update_at(cache, new, idx):
-    """cache (B, T, ...), new (B, S, ...) -> updated at write rows `idx`.
+def _update_at(cache, new, layer, idx):
+    """cache (n, B, T, ...) stacked, new (B, S, ...) -> layer `layer`
+    updated at write rows `idx`, in place: only the new rows are written.
 
     idx is a scalar (uniform batch: S contiguous rows starting there), or
     a per-slot (B|1, S) row matrix (continuous batching: every sequence
@@ -367,13 +372,22 @@ def _update_at(cache, new, idx):
     tokens past a slot's valid count."""
     idx = jnp.asarray(idx)
     if idx.ndim == 0:
-        i = (0, idx) + (0,) * (cache.ndim - 2)
-        return jax.lax.dynamic_update_slice(cache, new.astype(cache.dtype), i)
-    B = cache.shape[0]
+        i = (layer, 0, idx) + (0,) * (cache.ndim - 3)
+        return jax.lax.dynamic_update_slice(
+            cache, new[None].astype(cache.dtype), i)
+    B = cache.shape[1]
     if idx.shape[0] == 1 and B > 1:
         idx = jnp.broadcast_to(idx, (B,) + idx.shape[1:])
-    return cache.at[jnp.arange(B)[:, None], idx].set(
+    return cache.at[layer, jnp.arange(B)[:, None], idx].set(
         new.astype(cache.dtype), mode="drop")
+
+
+def _layer_rows(cache, layer, Tb: int):
+    """Rows 0..Tb-1 of layer `layer` of a stacked (n, B, T, ...) leaf —
+    the dense read slice, taken without slicing out the whole layer."""
+    start = (layer,) + (0,) * (cache.ndim - 1)
+    size = (1, cache.shape[1], Tb) + cache.shape[3:]
+    return jax.lax.dynamic_slice(cache, start, size)[0]
 
 
 def _q_pos(pos):
@@ -472,10 +486,20 @@ def _decode_ffn(p_l, cfg, x):
     return x + f
 
 
-def decode_attn(p_l, cfg, x, cache_k, cache_v, pos, window, cross=None,
+def decode_attn(p_l, cfg, x, cache, layer, pos, window, cross=None,
                 pinfo=None, n_valid=None, kv_len=None, use_ragged=False,
-                cache_ks=None, cache_vs=None, paged=None):
-    """Single-step attention using + updating the cache slice.
+                paged=None):
+    """Single-step attention for layer `layer`, reading and updating the
+    segment's stacked caches in place.
+
+    cache: {"k", "v"} stacked (n, B, T, Hk, Dh) post-RoPE keys/values
+    (+ "k_scale"/"v_scale" (n, B, T, Hk) f32 when kv_cache_dtype is
+    quantized); layer: int32 index into the stack. Only the step's new
+    rows are written — k_new/v_new are quantized as they land (per-head,
+    per-position amax scales), codes and scales share `widx` so ring
+    wraparound and padded-token drops stay in lockstep — and reads take
+    rows of that layer where they lie: the ragged kernel indexes the
+    stack by layer, the dense fallback slices Tb rows of the layer.
 
     x: (B, S, d) — S is 1 for decode ticks, the chunk size during chunked
     prefill (padded tokens suppressed via n_valid). pos: scalar or
@@ -484,25 +508,21 @@ def decode_attn(p_l, cfg, x, cache_k, cache_v, pos, window, cross=None,
     per slot. pinfo: hoisted decode_positions dict (decode_segment builds
     it once per segment); kv_len: static read-slice bucket; use_ragged:
     route S=1 attention through the length-aware Pallas kernel.
-    cache_ks/cache_vs: (B, T, Hk) f32 scale caches when kv_cache_dtype is
-    quantized — k_new/v_new are quantized as they land (per-head,
-    per-position amax scales), codes and scales share `widx` so ring
-    wraparound and padded-token drops stay in lockstep.
 
-    paged: optional (block_table, page, Tc) — caches are then ROW POOLS
-    ((R, Hk, Dh) / (R, Hk) scales, no batch axis) addressed through the
-    per-slot block table: `widx` translates to physical rows for writes
-    (codes + scales share the translated rows, so page lockstep is
-    automatic) and reads gather logical rows 0..Tb-1 in order, making
-    paged attention bitwise-identical to the contiguous slice. S=1
-    ragged decode skips the gather entirely: the Pallas kernel indexes
-    KV pages through the block table itself. Returns the new caches as
-    a dict."""
+    paged: optional (block_table, page, Tc) — caches are then stacked ROW
+    POOLS ((n, R, Hk, Dh) / (n, R, Hk) scales, no batch axis) addressed
+    through the per-slot block table: `widx` translates to physical rows
+    for writes (codes + scales share the translated rows, so page
+    lockstep is automatic) and dense reads gather logical rows 0..Tb-1
+    in order, making paged attention bitwise-identical to the contiguous
+    slice. S=1 ragged decode skips the gather entirely: the Pallas kernel
+    indexes KV pages through the block table itself. Returns (x, the
+    updated cache dict)."""
     if paged is not None:
         block_table, page, T = paged
-        R = cache_k.shape[0]
+        R = cache["k"].shape[1]
     else:
-        T = cache_k.shape[1]
+        T = cache["k"].shape[2]
     # windows are static Segment.window ints; a traced window must fail
     # loudly here — silently treating it as full attention would write
     # past a ring-sized cache.
@@ -514,6 +534,18 @@ def decode_attn(p_l, cfg, x, cache_k, cache_v, pos, window, cross=None,
     q_pos, widx, k_pos, Tb = (pinfo["q_pos"], pinfo["widx"], pinfo["k_pos"],
                               pinfo["Tb"])
     S = x.shape[1]
+
+    def read(leaf):
+        # dense reads: logical rows 0..Tb-1 of this layer, in order
+        if paged is not None:
+            return leaf[layer, _gather_rows(block_table, page, Tb)]
+        return _layer_rows(leaf, layer, Tb)
+
+    def kernel_rows(leaf):
+        # the rows of this layer a kernel may read, where they lie
+        return leaf[layer] if paged is not None \
+            else _layer_rows(leaf, layer, Tb)
+
     # A multi-token chunk on a RING cache cannot use write-then-read: the
     # chunk's writes at rows (pos+j) % W destroy positions pos+j-W that
     # EARLIER chunk queries still need. The speculative fused-verify path
@@ -522,17 +554,9 @@ def decode_attn(p_l, cfg, x, cache_k, cache_v, pos, window, cross=None,
     # keys] below. S=1 decode ticks keep the write-then-read fast path.
     chunk_ring = ring and S > 1
     if chunk_ring:
-        if paged is not None:
-            grows = _gather_rows(block_table, page, Tb)    # (B, Tb)
-            pre_k = cache_k[grows]
-            pre_v = cache_v[grows]
-            pre_scales = ((cache_ks[grows], cache_vs[grows])
-                          if spec.quantized else None)
-        else:
-            pre_k = cache_k[:, :Tb]
-            pre_v = cache_v[:, :Tb]
-            pre_scales = ((cache_ks[:, :Tb], cache_vs[:, :Tb])
-                          if spec.quantized else None)
+        pre_k, pre_v = read(cache["k"]), read(cache["v"])
+        pre_scales = ((read(cache["k_scale"]), read(cache["v_scale"]))
+                      if spec.quantized else None)
     h = L.rms_norm(x, p_l["ln_attn"], cfg.logical_norm_eps)
     # project current token k, v and write to cache
     src = h
@@ -542,29 +566,23 @@ def decode_attn(p_l, cfg, x, cache_k, cache_v, pos, window, cross=None,
         k_new = L.rms_norm(k_new, p_l["attn"]["k_norm"])
     if not cfg.use_rel_pos_bias:
         k_new = L.apply_rope(k_new, q_pos, cfg.rope_theta)
+    new = {"k": k_new, "v": v_new}
     if spec.quantized:
         # quantize-on-write: post-RoPE keys/values -> codes + scales
-        k_new, ks_new = quant.quantize(k_new, spec)    # (B,S,Hk,dh),(B,S,Hk)
-        v_new, vs_new = quant.quantize(v_new, spec)
+        k_new, new["k_scale"] = quant.quantize(k_new, spec)  # (B,S,Hk,dh),
+        v_new, new["v_scale"] = quant.quantize(v_new, spec)  # (B,S,Hk)
+        new["k"], new["v"] = k_new, v_new
+    cache = dict(cache)
     if paged is not None:
         # codes and scales land at the SAME translated physical rows:
         # quantized lockstep holds per page by construction
         rows = _paged_rows(block_table, widx, page, T, R)
-        if spec.quantized:
-            cache_ks = cache_ks.at[rows].set(
-                ks_new.astype(cache_ks.dtype), mode="drop")
-            cache_vs = cache_vs.at[rows].set(
-                vs_new.astype(cache_vs.dtype), mode="drop")
-        cache_k = cache_k.at[rows].set(
-            k_new.astype(cache_k.dtype), mode="drop")
-        cache_v = cache_v.at[rows].set(
-            v_new.astype(cache_v.dtype), mode="drop")
+        for name, val in new.items():
+            cache[name] = cache[name].at[layer, rows].set(
+                val.astype(cache[name].dtype), mode="drop")
     else:
-        if spec.quantized:
-            cache_ks = _update_at(cache_ks, ks_new, widx)
-            cache_vs = _update_at(cache_vs, vs_new, widx)
-        cache_k = _update_at(cache_k, k_new, widx)
-        cache_v = _update_at(cache_v, v_new, widx)
+        for name, val in new.items():
+            cache[name] = _update_at(cache[name], val, layer, widx)
     if chunk_ring:
         # pre-chunk key positions at depth pos (last written pos-1);
         # never-written rows go past every chunk query (pos + S). Fresh
@@ -577,8 +595,8 @@ def decode_attn(p_l, cfg, x, cache_k, cache_v, pos, window, cross=None,
         if spec.quantized:
             pre_k = quant.dequantize(pre_k, pre_scales[0], x.dtype)
             pre_v = quant.dequantize(pre_v, pre_scales[1], x.dtype)
-            fresh_k = quant.dequantize(k_new, ks_new, x.dtype)
-            fresh_v = quant.dequantize(v_new, vs_new, x.dtype)
+            fresh_k = quant.dequantize(k_new, new["k_scale"], x.dtype)
+            fresh_v = quant.dequantize(v_new, new["v_scale"], x.dtype)
         pre_pos = _ring_k_pos(jnp.asarray(pos) - 1, T,
                               far_offset=S + 1)[:, :Tb]
         chunk_pos = jnp.where(widx < T, q_pos, q_pos[:, -1:] + 1)
@@ -593,44 +611,33 @@ def decode_attn(p_l, cfg, x, cache_k, cache_v, pos, window, cross=None,
              chunk_pos], axis=1)
         a, _ = L.attention_block(p_l["attn"], cfg, h, window=window,
                                  q_pos=q_pos, k_pos=kp, kv=(kcat, vcat))
+    elif use_ragged and S == 1:
+        # the ragged kernel reads layer `layer` of the stacked codes in
+        # place (and, paged, gathers KV pages through the block table in
+        # its own index map): no per-layer slice or row gather. Scales
+        # go over as this layer's read rows (paged: the layer's pool): a
+        # TPU lays a scale stack out position-minor (a row is Hk floats)
+        # and XLA relayouts a kernel operand whole, so the operand is the
+        # rows read, not the stack.
+        lengths = jnp.broadcast_to(pinfo["lengths"], (x.shape[0],))
+        kv_scales = ((kernel_rows(cache["k_scale"]),
+                      kernel_rows(cache["v_scale"]))
+                     if spec.quantized else None)
+        a, _ = L.attention_block(
+            p_l["attn"], cfg, h, window=window, q_pos=q_pos, k_pos=k_pos,
+            kv=(cache["k"], cache["v"]), ragged=(lengths, layer, Tb),
+            kv_scales=kv_scales,
+            paged_kv=None if paged is None else (block_table, page))
     else:
-        lengths = jnp.broadcast_to(pinfo["lengths"], (x.shape[0],)) \
-            if use_ragged else None
-        if paged is not None and use_ragged and S == 1:
-            # the paged ragged kernel gathers KV pages through the block
-            # table in its own index map — no row gather materializes
-            kv_scales = (cache_ks, cache_vs) if spec.quantized else None
-            a, _ = L.attention_block(p_l["attn"], cfg, h, window=window,
-                                     q_pos=q_pos, k_pos=k_pos,
-                                     kv=(cache_k, cache_v),
-                                     ragged_lengths=lengths,
-                                     kv_scales=kv_scales,
-                                     paged_kv=(block_table, page, Tb))
-        elif paged is not None:
-            # dense fallback: gather logical rows 0..Tb-1 in order —
-            # bitwise-identical to the contiguous read slice
-            grows = _gather_rows(block_table, page, Tb)
-            kr, vr = cache_k[grows], cache_v[grows]
-            kv_scales = ((cache_ks[grows], cache_vs[grows])
-                         if spec.quantized else None)
-            a, _ = L.attention_block(p_l["attn"], cfg, h, window=window,
-                                     q_pos=q_pos, k_pos=k_pos,
-                                     kv=(kr, vr), ragged_lengths=lengths,
-                                     kv_scales=kv_scales)
-        else:
-            # read slice: O(bucket) bytes, not O(T) — rows past the
-            # kv-len bucket are allocated-but-unwritten (masked anyway),
-            # never read
-            kr = cache_k[:, :Tb] if Tb < T else cache_k
-            vr = cache_v[:, :Tb] if Tb < T else cache_v
-            kv_scales = None
-            if spec.quantized:
-                kv_scales = (cache_ks[:, :Tb] if Tb < T else cache_ks,
-                             cache_vs[:, :Tb] if Tb < T else cache_vs)
-            a, _ = L.attention_block(p_l["attn"], cfg, h, window=window,
-                                     q_pos=q_pos, k_pos=k_pos,
-                                     kv=(kr, vr), ragged_lengths=lengths,
-                                     kv_scales=kv_scales)
+        # dense fallback: O(bucket) rows, not O(T) — rows past the kv-len
+        # bucket are allocated-but-unwritten (masked anyway), never read;
+        # paged reads are bitwise-identical to the contiguous slice
+        kv_scales = ((read(cache["k_scale"]), read(cache["v_scale"]))
+                     if spec.quantized else None)
+        a, _ = L.attention_block(p_l["attn"], cfg, h, window=window,
+                                 q_pos=q_pos, k_pos=k_pos,
+                                 kv=(read(cache["k"]), read(cache["v"])),
+                                 kv_scales=kv_scales)
     x = x + a
     if cross is not None:
         cp, ck, cv = cross
@@ -640,64 +647,63 @@ def decode_attn(p_l, cfg, x, cache_k, cache_v, pos, window, cross=None,
                                  q_pos=q_pos, k_pos=jnp.arange(ck.shape[1]),
                                  kv=(ck, cv), causal=False)
         x = x + c
-    new_cache = {"k": cache_k, "v": cache_v}
-    if spec.quantized:
-        new_cache["k_scale"] = cache_ks
-        new_cache["v_scale"] = cache_vs
-    return _decode_ffn(p_l, cfg, x), new_cache
+    return _decode_ffn(p_l, cfg, x), cache
 
 
-def decode_mla(p_l, cfg, x, cache_lat, pos, pinfo=None, n_valid=None,
-               kv_len=None, cache_lat_s=None, paged=None):
-    """pos: scalar or per-slot (B,). MLA caches are always linear (full
-    attention); the latent read is bucket-sliced like the k/v caches.
-    Quantized mode stores latent codes + a per-position scale (the latent
-    is head-free, so one scale per cached row); the absorbed-matrix
-    attention consumes the densely-dequantized slice (no MLA Pallas
-    kernel — the dequant IS the reference path). paged: optional
-    (block_table, page, Tc) — latents pool their rows exactly like the
-    k/v caches (decode_attn), codes + scales on the same physical rows.
-    Returns (out, cache dict)."""
+def decode_mla(p_l, cfg, x, cache, layer, pos, pinfo=None, n_valid=None,
+               kv_len=None, paged=None):
+    """MLA for layer `layer` of the segment's stacked latent caches,
+    updated in place. cache: {"latent": (n, B, T, r+qr)} (+
+    "latent_scale" (n, B, T) quantized). pos: scalar or per-slot (B,).
+    MLA caches are always linear (full attention); the latent read is
+    bucket-sliced like the k/v caches. Quantized mode stores latent
+    codes + a per-position scale (the latent is head-free, so one scale
+    per cached row); the absorbed-matrix attention consumes the densely-
+    dequantized slice (no MLA Pallas kernel — the dequant IS the
+    reference path). paged: optional (block_table, page, Tc) — latents
+    pool their rows exactly like the k/v caches (decode_attn), codes +
+    scales on the same physical rows. Returns (out, cache dict)."""
     if paged is not None:
         block_table, page, T = paged
-        R = cache_lat.shape[0]
+        R = cache["latent"].shape[1]
     else:
-        T = cache_lat.shape[1]
+        T = cache["latent"].shape[2]
     spec = kv_quant_spec(cfg)
     if pinfo is None:
         pinfo = decode_positions(pos, x.shape[1], T, False, n_valid=n_valid,
                                  kv_len=kv_len)
     q_pos, widx, Tb = pinfo["q_pos"], pinfo["widx"], pinfo["Tb"]
     h = L.rms_norm(x, p_l["ln_attn"], cfg.logical_norm_eps)
-    lat_new = L.mla_latent(p_l["attn"], cfg, h, k_pos=q_pos)  # (B,S,w)
-    if spec.quantized:
-        lat_new, ls_new = quant.quantize(lat_new, spec)       # scale (B,S)
+    new = {"latent": L.mla_latent(p_l["attn"], cfg, h, k_pos=q_pos)}
+    if spec.quantized:                                  # scale (B,S)
+        new["latent"], new["latent_scale"] = quant.quantize(new["latent"],
+                                                            spec)
+    cache = dict(cache)
     if paged is not None:
         rows = _paged_rows(block_table, widx, page, T, R)
-        if spec.quantized:
-            cache_lat_s = cache_lat_s.at[rows].set(
-                ls_new.astype(cache_lat_s.dtype), mode="drop")
-        cache_lat = cache_lat.at[rows].set(
-            lat_new.astype(cache_lat.dtype), mode="drop")
         grows = _gather_rows(block_table, page, Tb)
-        latr = cache_lat[grows]
-        if spec.quantized:
-            latr = quant.dequantize(latr, cache_lat_s[grows], x.dtype)
+        for name, val in new.items():
+            cache[name] = cache[name].at[layer, rows].set(
+                val.astype(cache[name].dtype), mode="drop")
+        read = lambda leaf: leaf[layer, grows]
     else:
-        if spec.quantized:
-            cache_lat_s = _update_at(cache_lat_s, ls_new, widx)
-        cache_lat = _update_at(cache_lat, lat_new, widx)
-        latr = cache_lat[:, :Tb] if Tb < T else cache_lat
-        if spec.quantized:
-            lsr = cache_lat_s[:, :Tb] if Tb < T else cache_lat_s
-            latr = quant.dequantize(latr, lsr, x.dtype)
+        for name, val in new.items():
+            cache[name] = _update_at(cache[name], val, layer, widx)
+        read = lambda leaf: _layer_rows(leaf, layer, Tb)
+    latr = read(cache["latent"])
+    if spec.quantized:
+        latr = quant.dequantize(latr, read(cache["latent_scale"]), x.dtype)
     a = L.mla_attention(p_l["attn"], cfg, h, latr, q_pos=q_pos,
                         k_pos=pinfo["k_pos"])
     x = x + a
-    new_cache = {"latent": cache_lat}
-    if spec.quantized:
-        new_cache["latent_scale"] = cache_lat_s
-    return _decode_ffn(p_l, cfg, x), new_cache
+    return _decode_ffn(p_l, cfg, x), cache
+
+
+# Segment kinds whose caches are indexed by position (KV caches): their
+# stacks ride in the decode loop's carry and each layer writes only its
+# new rows. Recurrent kinds (rwkv/mamba) rewrite their whole state every
+# step, so their per-layer state stays scan xs/ys.
+_KV_KINDS = ("attn", "mla")
 
 
 def decode_segment(p_seg, cache, seg: Segment, cfg: ModelConfig, x, pos,
@@ -710,7 +716,14 @@ def decode_segment(p_seg, cache, seg: Segment, cfg: ModelConfig, x, pos,
     addressed through the per-slot table; the logical capacity Tc is the
     table's row span (ring segments still cap it at their window), which
     covers every reachable position since requests never exceed max_len
-    <= table capacity."""
+    <= table capacity.
+
+    KV-cache segments (_KV_KINDS) carry their stacked caches through the
+    layer scan with the layer index: each layer writes its new rows into
+    the stack and reads its layer where it lies, so no per-layer slice of
+    a cache and no rebuilt copy of the stack is ever materialized. A
+    segment of seg.n layers reads and writes layers 0..seg.n-1 of a stack
+    that may hold more (draft_step's head layers)."""
     K = cfg.altup.K
     S = x.shape[1]
     pg_seg = None
@@ -743,14 +756,14 @@ def decode_segment(p_seg, cache, seg: Segment, cfg: ModelConfig, x, pos,
         pinfo = None
 
     if seg.kind == "shared_attn":
+        # one unstacked block: its caches are a stack of one (a free
+        # leading axis) read and written at layer 0
         def layer_fn(xa):
-            out, nc = decode_attn(p_seg, cfg, xa, cache["k"], cache["v"],
-                                  pos, seg.window, pinfo=pinfo,
-                                  use_ragged=use_ragged,
-                                  cache_ks=cache.get("k_scale"),
-                                  cache_vs=cache.get("v_scale"),
-                                  paged=pg_seg)
-            layer_fn.new_cache = nc
+            out, nc = decode_attn(p_seg, cfg, xa,
+                                  {k: c[None] for k, c in cache.items()},
+                                  0, pos, seg.window, pinfo=pinfo,
+                                  use_ragged=use_ragged, paged=pg_seg)
+            layer_fn.new_cache = {k: c[0] for k, c in nc.items()}
             return out
         if cfg.altup.enabled:
             sel = alt.block_selector(seg.layer_offset, K, cfg.altup.selection)
@@ -761,14 +774,15 @@ def decode_segment(p_seg, cache, seg: Segment, cfg: ModelConfig, x, pos,
         return x, layer_fn.new_cache
 
     n = seg.n
+    kv = seg.kind in _KV_KINDS
     sels = (jnp.stack([alt.block_selector(i, K, cfg.altup.selection)
                        for i in range(seg.layer_offset,
                                       seg.layer_offset + n)])
             if cfg.altup.enabled else jnp.zeros((n, 1)))
 
-    def body(x, per_layer):
-        p_l, cache_l, sel, cross_l = per_layer
-        window = seg.window
+    def body(carry, per_layer):
+        x, stack = carry
+        p_l, state, sel, cross_l, layer = per_layer
         box = {}
 
         def layer_fn(xa):
@@ -776,34 +790,24 @@ def decode_segment(p_seg, cache, seg: Segment, cfg: ModelConfig, x, pos,
                 cross = None
                 if cross_l is not None:
                     cross = (cross_l[0], cross_l[1]["k"], cross_l[1]["v"])
-                out, nc = decode_attn(p_l, cfg, xa, cache_l["k"],
-                                      cache_l["v"], pos, window,
-                                      cross=cross, pinfo=pinfo,
-                                      use_ragged=use_ragged,
-                                      cache_ks=cache_l.get("k_scale"),
-                                      cache_vs=cache_l.get("v_scale"),
-                                      paged=pg_seg)
-                box["cache"] = nc
+                out, box["stack"] = decode_attn(
+                    p_l, cfg, xa, stack, layer, pos, seg.window,
+                    cross=cross, pinfo=pinfo, use_ragged=use_ragged,
+                    paged=pg_seg)
             elif seg.kind == "mla":
-                out, nc = decode_mla(p_l, cfg, xa, cache_l["latent"], pos,
-                                     pinfo=pinfo,
-                                     cache_lat_s=cache_l.get("latent_scale"),
-                                     paged=pg_seg)
-                box["cache"] = nc
+                out, box["stack"] = decode_mla(p_l, cfg, xa, stack, layer,
+                                               pos, pinfo=pinfo,
+                                               paged=pg_seg)
             elif seg.kind == "rwkv":
-                state = {"wkv": cache_l["wkv"],
-                         "shift_tm": cache_l["shift_tm"],
-                         "shift_cm": cache_l["shift_cm"]}
                 from repro.models.transformer import rwkv_layer
-                out, _, st = rwkv_layer(p_l, cfg, xa, state)
-                box["cache"] = {"wkv": st["wkv"],
+                out, _, st = rwkv_layer(p_l, cfg, xa, dict(state))
+                box["state"] = {"wkv": st["wkv"],
                                 "shift_tm": st["shift_tm"],
                                 "shift_cm": st["shift_cm"]}
             elif seg.kind == "mamba":
                 from repro.models.transformer import mamba_layer
-                state = {"conv": cache_l["conv"], "ssm": cache_l["ssm"]}
-                out, _, st = mamba_layer(p_l, cfg, xa, state)
-                box["cache"] = {"conv": st["conv"], "ssm": st["ssm"]}
+                out, _, st = mamba_layer(p_l, cfg, xa, dict(state))
+                box["state"] = {"conv": st["conv"], "ssm": st["ssm"]}
             else:
                 raise ValueError(seg.kind)
             return out
@@ -813,11 +817,14 @@ def decode_segment(p_seg, cache, seg: Segment, cfg: ModelConfig, x, pos,
                                 p_l["altup_g"], use_fused=use_fused)
         else:
             x = layer_fn(x)
-        return x, box["cache"]
+        return (x, box.get("stack")), box.get("state")
 
-    xs = (p_seg, cache, sels, cross_stack)
-    x, new_cache = jax.lax.scan(body, x, xs, unroll=seg.n if cfg.scan_unroll else 1)
-    return x, new_cache
+    xs = (p_seg, None if kv else cache, sels, cross_stack,
+          jnp.arange(n, dtype=jnp.int32))
+    (x, stack), states = jax.lax.scan(
+        body, (x, cache if kv else None), xs,
+        unroll=seg.n if cfg.scan_unroll else 1)
+    return x, (stack if kv else states)
 
 
 def decode_step(params, cfg: ModelConfig, caches, tokens, pos, *,
@@ -966,15 +973,18 @@ def draft_step(params, cfg: ModelConfig, caches, tokens, pos, *,
             continue
         if m > 0:
             # partial segment: run the head layers through the normal
-            # scanned body on sliced param/cache stacks, then merge the
-            # updated cache head back over the untouched tail layers
+            # scanned body on sliced param stacks. KV stacks are written
+            # in place at layers 0..m-1; recurrent state is sliced to the
+            # head and merged back over the untouched tail layers
             head = dataclasses.replace(seg, n=m)
-            x, nc = decode_segment(_tree_head(p_seg, m), _tree_head(cache, m),
+            kv = seg.kind in _KV_KINDS
+            x, nc = decode_segment(_tree_head(p_seg, m),
+                                   cache if kv else _tree_head(cache, m),
                                    head, cfg, x, pos, mesh=mesh,
                                    n_valid=n_valid, kv_len=kv_len,
                                    use_ragged=use_ragged,
                                    use_fused=use_fused, paged=paged)
-            new_caches[f"seg{si}"] = _tree_merge(cache, nc, m)
+            new_caches[f"seg{si}"] = nc if kv else _tree_merge(cache, nc, m)
         if cfg.altup.enabled:
             # predict-only tail: layers [m, n) collapse to ONE composed
             # K x K mixer (shared_attn blocks carry an unstacked (K, K))

@@ -168,27 +168,29 @@ def attention_block(p: dict, cfg: ModelConfig, x: jax.Array, *,
                     window, q_pos, k_pos, kv: Optional[tuple] = None,
                     x_kv: Optional[jax.Array] = None, bias=None,
                     causal: Optional[bool] = None, banded: bool = False,
-                    ragged_lengths: Optional[jax.Array] = None,
+                    ragged: Optional[tuple] = None,
                     kv_scales: Optional[tuple] = None,
                     paged_kv: Optional[tuple] = None):
     """Full attention sub-block (no residual, no pre-norm — caller owns those).
 
     Returns (out, (k, v)) so callers can populate KV caches.
     kv: precomputed (k, v) (decode path with cache); x_kv: cross-attn source.
-    ragged_lengths: per-slot (B,) valid-cache-row counts — when given and
-    S == 1, attention runs through the length-aware Pallas decode kernel
-    (kernels/ragged_decode_attention.py) instead of the dense masked sdpa.
-    The caller guarantees row `t` of the cache is valid iff t < length —
-    this subsumes causal, per-slot-depth AND ring-window masking, which is
-    why no q_pos/k_pos reach the kernel.
-    kv_scales: (k_scale, v_scale) (B, T, Hk) f32 — `kv` holds quantized
-    codes (int8/fp8, cfg.kv_cache_dtype). The ragged kernel fuses the
-    dequant into its kv-block load; every other path (chunked prefill
-    S > 1, dense fallback on interpret backends) dequantizes here and is
-    the kernel's oracle.
-    paged_kv: (block_table, page, t_max) — `kv` is then a batchless page
-    POOL ((R, Hk, Dh), scales (R, Hk)) and attention must take the ragged
-    kernel path (paged pools have no dense layout for sdpa); the kernel
+    ragged: (lengths, layer, t_max) — S == 1 attention runs through the
+    length-aware Pallas decode kernel (kernels/ragged_decode_attention.py)
+    instead of the dense masked sdpa. `kv` (and kv_scales) are then a
+    segment's STACKED caches, (n, B, T, Hk, Dh), and the kernel reads
+    layer `layer` where it lies, rows < t_max (the kv bucket). lengths:
+    per-slot (B,) valid-cache-row counts; the caller guarantees row `t`
+    is valid iff t < length — this subsumes causal, per-slot-depth AND
+    ring-window masking, which is why no q_pos/k_pos reach the kernel.
+    kv_scales: (k_scale, v_scale) f32 per row and kv head — `kv` holds
+    quantized codes (int8/fp8, cfg.kv_cache_dtype). On the ragged path
+    they are stacked like `kv` or the read layer's alone, and the kernel
+    fuses the dequant into its kv-block load; every other path (chunked
+    prefill S > 1, dense fallback on interpret backends) dequantizes here
+    and is the kernel's oracle.
+    paged_kv: (block_table, page) — with `ragged`, `kv` is then a stacked
+    batchless page POOL ((n, R, Hk, Dh), scales (n, R, Hk)); the kernel
     indexes KV pages through the block table in its own index map, so no
     gathered copy of the cache is materialized.
     """
@@ -211,30 +213,29 @@ def attention_block(p: dict, cfg: ModelConfig, x: jax.Array, *,
             k = apply_rope(k, k_pos, cfg.rope_theta)
     else:
         k, v = kv
-    use_ragged = (ragged_lengths is not None and q.shape[1] == 1
-                  and kv is not None and bias is None and causal)
     use_banded = (banded and isinstance(window, int) and window > 0
                   and kv is None and bias is None and causal
                   and x_kv is None)
-    if use_ragged:
-        from repro.kernels import ops as kops
-        if paged_kv is not None:
-            bt, page, t_max = paged_kv
-            ks, vs = kv_scales if kv_scales is not None else (None, None)
-            out = kops.paged_ragged_decode_attn(q, k, v, ragged_lengths,
-                                                bt, ks, vs, page=page,
-                                                t_max=t_max)
-        elif kv_scales is not None:
-            out = kops.ragged_decode_attn(q, k, v, ragged_lengths,
-                                          kv_scales[0], kv_scales[1])
-        else:
-            out = kops.ragged_decode_attn(q, k, v, ragged_lengths)
-    else:
-        if paged_kv is not None:
+    if ragged is not None:
+        if not (q.shape[1] == 1 and kv is not None and bias is None
+                and causal):
             raise ValueError(
-                "paged_kv requires the ragged decode path (S == 1, kv "
-                "cache, causal, no bias) — a paged pool has no dense "
-                "(B, T, ...) layout for sdpa")
+                "the ragged decode path needs S == 1, a kv cache, causal "
+                "masking and no bias — stacked caches and paged pools have "
+                "no dense (B, T, ...) layout for sdpa")
+        from repro.kernels import ops as kops
+        lengths, layer, t_max = ragged
+        ks, vs = kv_scales if kv_scales is not None else (None, None)
+        if paged_kv is not None:
+            bt, page = paged_kv
+            out = kops.paged_ragged_decode_attn(q, k, v, lengths, bt, ks, vs,
+                                                page=page, t_max=t_max,
+                                                layer=layer)
+        else:
+            out = kops.ragged_decode_attn(q, k, v, lengths, ks, vs,
+                                          layer=layer, t_max=t_max)
+    else:
+        assert paged_kv is None, "paged_kv requires the ragged decode path"
         if kv_scales is not None:
             from repro.kernels import quant
             k = quant.dequantize(k, kv_scales[0], x.dtype)

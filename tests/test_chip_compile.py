@@ -13,6 +13,7 @@ imports this file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -105,19 +106,12 @@ def test_fused_altup_decode_compiles(one_chip):
     assert compiled_kernels(hlo) == {"altup_predict_correct"}
 
 
-@pytest.mark.parametrize("layout", ["contiguous", "int8", "paged"])
-def test_decode_step_compiles_with_kernels(one_chip, layout):
-    """One whole 28-layer decode step with both decode kernels on, over
-    each cache layout the engine serves: the kernels are chosen when the
-    step is traced, so the step must hold them as custom calls, not
-    their interpreted bodies."""
-    import repro.kernels
-    # imported before the patch below: a mode chosen at import would be
-    # the CPU's and leave the step without custom calls
-    import repro.kernels.ops  # noqa: F401
+def _qwen_step(one_chip, layout):
+    """(cfg, params, caches, block-table kwargs) of the 28-layer
+    Qwen3-0.6B decode step, as shapes placed on the described chip, over
+    one of the cache layouts the engine serves."""
     from repro.configs import get_config
-    from repro.models.decode import (decode_step, init_cache,
-                                     init_paged_cache)
+    from repro.models.decode import init_cache, init_paged_cache
     from repro.models.transformer import init_params
 
     cfg = get_config("qwen3-0.6b", altup_k=2).replace(
@@ -134,20 +128,105 @@ def test_decode_step_compiles_with_kernels(one_chip, layout):
         kw = {"block_table": _spec(one_chip, (B, T // PAGE), jnp.int32)}
     else:
         caches = place(jax.eval_shape(lambda: init_cache(cfg, B, T)))
-    tokens = _spec(one_chip, (B, 1), jnp.int32)
-    pos = _spec(one_chip, (B,), jnp.int32)
-    step = functools.partial(decode_step, cfg=cfg, kv_len=T,
-                             page_size=PAGE if layout == "paged" else 0)
+    return cfg, params, caches, kw
+
+
+def _compile_for_chip(fn, *args, **kwargs):
+    """Compile `fn` with the kernels in the chip's compiled mode."""
+    import repro.kernels
+    # imported before the patch below: a mode chosen at import would be
+    # the CPU's and leave the step without custom calls
+    import repro.kernels.ops  # noqa: F401
     with pytest.MonkeyPatch.context() as mp:
         # this process runs on the CPU; steer the kernels to the chip's
         # compiled mode for this trace only (and trace afresh)
         mp.setattr(repro.kernels, "default_interpret", lambda: False)
         jax.clear_caches()
         try:
-            hlo = jax.jit(step).lower(params, caches=caches, tokens=tokens,
-                                      pos=pos, **kw).compile().as_text()
+            return fn.lower(*args, **kwargs).compile()
         finally:
             jax.clear_caches()
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "int8", "paged"])
+def test_decode_step_compiles_with_kernels(one_chip, layout):
+    """One whole 28-layer decode step with both decode kernels on, over
+    each cache layout the engine serves: the kernels are chosen when the
+    step is traced, so the step must hold them as custom calls, not
+    their interpreted bodies."""
+    from repro.models.decode import decode_step
+
+    cfg, params, caches, kw = _qwen_step(one_chip, layout)
+    tokens = _spec(one_chip, (B, 1), jnp.int32)
+    pos = _spec(one_chip, (B,), jnp.int32)
+    step = functools.partial(decode_step, cfg=cfg, kv_len=T,
+                             page_size=PAGE if layout == "paged" else 0)
+    hlo = _compile_for_chip(jax.jit(step), params, caches=caches,
+                            tokens=tokens, pos=pos, **kw).as_text()
     attn = ("paged_ragged_decode_attention" if layout == "paged"
             else "ragged_decode_attention")
     assert compiled_kernels(hlo) == {attn, "altup_predict_correct"}
+
+
+def _ops_of_shape(hlo: str, shapes) -> list:
+    """(op, result shape) of every copy, AllocateBuffer custom call,
+    dynamic-slice and scatter in the compiled text, fused computations
+    included, whose result has one of `shapes`."""
+    found = []
+    for line in hlo.splitlines():
+        m = re.search(r"= \w+\[([\d,]*)\]\S* ([\w-]+)\(", line)
+        if not m:
+            continue
+        op = m.group(2)
+        if op == "custom-call":
+            if 'custom_call_target="AllocateBuffer"' not in line:
+                continue
+            op = "AllocateBuffer"
+        elif op not in ("copy", "dynamic-slice", "scatter"):
+            continue
+        shape = tuple(int(d) for d in m.group(1).split(",") if d)
+        if shape in shapes:
+            found.append((op, shape))
+    return found
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "int8", "paged"])
+def test_fused_step_keeps_the_cache_in_place(one_chip, layout):
+    """The served step, compiled as Engine._fused compiles it (caches and
+    `seen` donated, a kv bucket of half the cache), moves no whole cache:
+    no copy, buffer allocation, dynamic-slice or scatter has the shape of
+    a whole layer of a cache leaf, and no copy, allocation or
+    dynamic-slice that of a whole stacked leaf. Each layer's row write
+    is a scatter whose result is the stack it updates in place, so
+    scatters are checked against whole layers only; the program's
+    scratch memory, below one layer of the k cache, shows that no stack
+    or layer of codes is rebuilt anywhere. The parent layout — caches as scan xs,
+    updated layers as ys — held two AllocateBuffer and two copies of
+    each whole stack, and a dynamic-slice and a scatter of a whole layer
+    per layer."""
+    from repro.models.decode import decode_sample_step
+    from repro.serve.sampling import blank_slot_params
+
+    cfg, params, caches, kw = _qwen_step(one_chip, layout)
+    s = functools.partial(_spec, one_chip)
+    seen = s((B, cfg.vocab_size), jnp.bool_)
+    sparams = {k: s(v.shape, v.dtype)
+               for k, v in blank_slot_params(B).items()}
+    tokens, pos, n_valid = s((B, 1), jnp.int32), s((B,), jnp.int32), \
+        s((B,), jnp.int32)
+    step = jax.jit(functools.partial(
+        decode_sample_step, cfg=cfg, kv_len=T // 2,
+        page_size=PAGE if layout == "paged" else 0),
+        donate_argnums=(1, 2))
+    compiled = _compile_for_chip(step, params, caches, seen, tokens, pos,
+                                 n_valid, sparams, **kw)
+    leaves = jax.tree_util.tree_leaves(caches)
+    stacks = {tuple(c.shape) for c in leaves}
+    layers = {tuple(c.shape[1:]) for c in leaves} \
+        | {(1,) + tuple(c.shape[1:]) for c in leaves}
+    moved = _ops_of_shape(compiled.as_text(), stacks | layers)
+    assert [m for m in moved
+            if m[1] in layers or m[0] != "scatter"] == []
+    layer_bytes = max(c.size // c.shape[0] * c.dtype.itemsize
+                      for c in leaves)
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes

@@ -156,6 +156,60 @@ def test_ragged_kernel_ring_window_wraparound():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("kind", ["ragged", "paged"])
+def test_decode_kernels_read_a_layer_of_the_stack(kind, dtype):
+    """A segment's stacked caches read at `layer` == the 4-D call on that
+    layer's caches, bitwise, at the first, a middle and the last layer —
+    the stack is indexed in the kernel's index maps, not sliced. Also
+    with stacked codes beside the read layer's own scales, and (ragged)
+    with a read bound t_max under T beside the 4-D call on rows < t_max:
+    the two forms the decode loop hands the kernels."""
+    from repro.kernels.ragged_decode_attention import (
+        paged_ragged_decode_attention as paged_raw)
+    n, B, T, Hk, rep, dh, page = 5, 3, 32, 2, 2, 16, 8
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (B, Hk, rep, dh), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (n, B, T, Hk, dh), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (n, B, T, Hk, dh), jnp.bfloat16)
+    scales = {}
+    if dtype == "int8":
+        k, v, ksc, vsc = _quant_cache(k, v, "int8")
+        scales = {"k_scale": ksc, "v_scale": vsc}
+    lens = jnp.asarray([0, 13, T], jnp.int32)
+    if kind == "paged":
+        # pool rows in a permuted page order, one table for every layer
+        perm = np.random.default_rng(5).permutation(B * T // page)
+        bt = jnp.asarray(perm.reshape(B, T // page), jnp.int32)
+        pool = lambda t: jnp.zeros((n, B * T) + t.shape[3:], t.dtype).at[
+            :, (bt[:, :, None] * page + jnp.arange(page)).reshape(B, T)
+        ].set(t)
+        k, v = pool(k), pool(v)
+        scales = {name: pool(s) for name, s in scales.items()}
+        call = lambda kk, vv, **kw: paged_raw(q, kk, vv, lens, bt, page=page,
+                                              t_max=T, interpret=True, **kw)
+    else:
+        call = lambda kk, vv, **kw: ragged_raw(q, kk, vv, lens, block_k=8,
+                                               interpret=True, **kw)
+    for layer in (0, n // 2, n - 1):
+        one = {name: s[layer] for name, s in scales.items()}
+        want = np.asarray(call(k[layer], v[layer], **one), np.float32)
+        got = call(k, v, layer=layer, **scales)
+        np.testing.assert_array_equal(np.asarray(got, np.float32), want)
+        if scales:
+            got = call(k, v, layer=layer, **one)
+            np.testing.assert_array_equal(np.asarray(got, np.float32), want)
+        if kind == "ragged":
+            t_max, short = 16, jnp.minimum(lens, 16)
+            rows = {name: s[:, :t_max] for name, s in one.items()}
+            want = ragged_raw(q, k[layer][:, :t_max], v[layer][:, :t_max],
+                              short, block_k=8, interpret=True, **rows)
+            got = ragged_raw(q, k, v, short, layer=layer, t_max=t_max,
+                             block_k=8, interpret=True, **rows)
+            np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                          np.asarray(want, np.float32))
+
+
 # --------------------------------------------------------------------------
 # quantized KV caches: fused-dequant kernels vs dense dequant oracles
 # --------------------------------------------------------------------------

@@ -311,3 +311,92 @@ def test_slot_caches_shard_under_mesh():
     r1 = eng.submit(prompt, sampling=sp)
     got = list(eng.run()[r1].tokens)
     assert got == want
+
+
+ROW_WRITE_CFGS = {
+    "contiguous": CFG.replace(name="srv-rw", n_layers=3),
+    "ring": CFG.replace(name="srv-rw-win", n_layers=3, window_size=4),
+    "paged": CFG.replace(name="srv-rw-pg", n_layers=3),
+    "int8": CFG.replace(name="srv-rw-i8", n_layers=3, kv_cache_dtype="int8"),
+}
+
+
+@pytest.mark.parametrize("layout", sorted(ROW_WRITE_CFGS))
+def test_step_writes_only_its_rows_in_place(layout):
+    """The fused step updates the stacked caches in place: after a decode
+    step (through the ragged kernel, which reads the stack by layer) and
+    after a prefill-chunk step, every cache leaf is bitwise its input
+    except the rows the step wrote, and those rows equal what each layer
+    projects when it runs alone over a stack of one layer."""
+    import dataclasses
+    from repro.models import decode as D
+    from repro.models.transformer import embed_tokens, layer_plan, unembed
+    cfg = ROW_WRITE_CFGS[layout].replace(ragged_decode_attn=True)
+    params = init_params(KEY, cfg)
+    B, T, page = 2, 16, 4
+    rng = np.random.default_rng(3)
+    paged = layout == "paged"
+    if paged:
+        caches = D.init_paged_cache(cfg, B, T, n_pages=B * T // page + 2,
+                                    page=page)
+        bt = jnp.asarray(rng.permutation(B * T // page + 2)[:B * T // page]
+                         .reshape(B, T // page), jnp.int32)
+        kw = {"block_table": bt, "page_size": page}
+    else:
+        caches = D.init_cache(cfg, B, T)
+        kw = {}
+    # stale contents everywhere, so an untouched row is a checked row
+    caches = jax.tree_util.tree_map(
+        lambda c: jnp.asarray(rng.uniform(0.5, 2.0, c.shape), c.dtype),
+        caches)
+    seg = layer_plan(cfg)[0]
+    Tc = min(T, seg.window) if seg.window else T
+
+    def written(pos, n_valid):
+        # (B, S) physical rows the step writes; -1 where dropped
+        S = max(n_valid)
+        q = np.asarray(pos)[:, None] + np.arange(S)[None]
+        rows = q % Tc
+        if paged:
+            rows = np.asarray(bt)[np.arange(B)[:, None], rows // page] \
+                * page + rows % page
+        return np.where(np.arange(S)[None] < np.asarray(n_valid)[:, None],
+                        rows, -1)
+
+    for pos, n_valid in (([5, 9], [1, 1]), ([2, 7], [3, 2])):
+        S = max(n_valid)
+        tokens = jnp.asarray(rng.integers(1, cfg.vocab_size, (B, S)),
+                             jnp.int32)
+        pos_d = jnp.asarray(pos, jnp.int32)
+        nv_d = jnp.asarray(n_valid, jnp.int32)
+        logits, new = D.decode_step(params, cfg, caches, tokens, pos_d,
+                                    n_valid=nv_d, kv_len=T, **kw)
+        # each layer alone, over a stack of one layer
+        x = embed_tokens(params, cfg, tokens)
+        alone = {}
+        for l in range(seg.n):
+            one = dataclasses.replace(seg, n=1,
+                                      layer_offset=seg.layer_offset + l)
+            p_l = jax.tree_util.tree_map(lambda a: a[l:l + 1],
+                                         params["seg0"])
+            c_l = {name: c[l:l + 1] for name, c in caches["seg0"].items()}
+            x, out = D.decode_segment(
+                p_l, c_l, one, cfg, x, pos_d, n_valid=nv_d, kv_len=T,
+                use_ragged=S == 1,
+                paged=(bt, page) if paged else None)
+            alone[l] = out
+        np.testing.assert_array_equal(
+            np.asarray(logits), np.asarray(unembed(params, cfg, x)))
+        rows = written(pos, n_valid)
+        for name, c in caches["seg0"].items():
+            old, got = np.asarray(c), np.asarray(new["seg0"][name])
+            mask = np.zeros(old.shape[1:1 + (1 if paged else 2)], bool)
+            for b in range(B):
+                for r in rows[b][rows[b] >= 0]:
+                    mask[(r,) if paged else (b, r)] = True
+            for l in range(seg.n):
+                np.testing.assert_array_equal(got[l][~mask], old[l][~mask])
+                np.testing.assert_array_equal(
+                    got[l][mask], np.asarray(alone[l][name][0])[mask])
+                assert not np.array_equal(got[l][mask], old[l][mask])
+        caches = new

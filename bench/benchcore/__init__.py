@@ -2,8 +2,9 @@
 the plain reference, the correctness comparison, the end-to-end arithmetic,
 the trace reduction, the byte/FLOP models and the table of peaks.
 
-Everything that belongs to one configuration, traffic mix, cell or
-per-layer metric lives in a data file or reader of its own under
-`bench/configs`, `bench/traffic`, `bench/cells` and `bench/metrics`, found
-by the name that `BENCHMARK.json` gives it.
+Everything that belongs to one configuration, traffic mix, cell,
+per-layer metric or model family lives in a data file or module of its
+own under `bench/configs`, `bench/traffic`, `bench/cells`,
+`bench/metrics` and `bench/families`, found by the name that
+`BENCHMARK.json` or the configuration file gives it.
 """

@@ -2,7 +2,8 @@
 
 After the window, a sample of the greedy requests the window finished,
 drawn from the seed and always holding the longest of them, is run once
-through the plain reference (`reference.py`) over prompt + served tokens.
+through the plain reference (`reference.py`, with the layers of the
+configuration's model family) over prompt + served tokens.
 At each served token the gap is the reference's best logit minus its
 logit of the served token: 0 where the program picked what the reference
 ranks first, small where rounding flipped a near tie, large where the
@@ -78,10 +79,12 @@ def _padded(req, toks, pad_min: int = PAD_MIN):
     return tokens, len(req.prompt) - 1, len(toks)
 
 
-def served_gaps(params, config: dict, picked, *, control: bool = False):
-    """Per sampled greedy request, the served gaps (and the control's)."""
+def served_gaps(params, family, config: dict, picked, *,
+                control: bool = False):
+    """Per sampled greedy request, the served gaps (and the control's),
+    under the reference of the model family `family`."""
     import jax.numpy as jnp
-    arch = reference.arch_of(config)
+    arch = family.arch(config)
     out = []
     for req, toks in picked:
         toks = list(toks)
@@ -90,17 +93,17 @@ def served_gaps(params, config: dict, picked, *, control: bool = False):
         targets[p0: p0 + n] = toks
         sg, cg = reference.gaps(params, jnp.asarray(tokens),
                                 jnp.asarray(targets), arch_items=arch,
-                                control=control)
+                                family=family, control=control)
         sl = slice(p0, p0 + n)
         out.append((np.asarray(sg)[sl], np.asarray(cg)[sl]))
     return out
 
 
-def served_nucleus(params, config: dict, picked):
+def served_nucleus(params, family, config: dict, picked):
     """Per sampled request that sampled, its served tokens' readings:
     (mass above each - top_p, log q, entropy, variance of log q)."""
     import jax.numpy as jnp
-    arch = reference.arch_of(config)
+    arch = family.arch(config)
     out = []
     for req, toks in picked:
         toks = list(toks)
@@ -113,7 +116,7 @@ def served_nucleus(params, config: dict, picked):
         above, lq, ent, var = (np.asarray(a)[:n] for a in reference.nucleus(
             params, jnp.asarray(tokens), jnp.asarray(rows),
             jnp.asarray(served), jnp.float32(req.temperature),
-            jnp.float32(req.top_p), arch_items=arch))
+            jnp.float32(req.top_p), arch_items=arch, family=family))
         out.append((above - req.top_p, lq, ent, var))
     return out
 

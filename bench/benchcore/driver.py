@@ -115,23 +115,12 @@ def load_peaks(kind: str, bench_dir: Path = spec.BENCH_DIR) -> dict:
     return table[kind]
 
 
-def program_config(config: dict):
-    """The program's ModelConfig for a configuration file, checked
-    against every number the file states."""
-    from repro.configs import get_config
-    m, alt = config["model"], config["altup"]
-    cfg = get_config(config["arch"], smoke=bool(config.get("smoke")),
-                     altup_k=int(alt["K"]))
-    want = {"n_layers": m["num_hidden_layers"], "d_model": m["hidden_size"],
-            "n_heads": m["num_attention_heads"],
-            "n_kv_heads": m["num_key_value_heads"],
-            "resolved_head_dim": m.get("head_dim") or
-            m["hidden_size"] // m["num_attention_heads"],
-            "d_ff": m["intermediate_size"], "vocab_size": m["vocab_size"],
-            "rope_theta": float(m["rope_theta"]),
-            "logical_norm_eps": float(m["rms_norm_eps"]),
-            "tie_embeddings": bool(m["tie_word_embeddings"]),
-            "dtype": m["torch_dtype"], "param_dtype": m["torch_dtype"]}
+def program_config(fam, config: dict):
+    """The program's ModelConfig for a configuration file, built by its
+    model family `fam` and checked against every number the file
+    states."""
+    cfg, want = fam.program_config(config)
+    alt = config["altup"]
     got = {k: getattr(cfg, k) for k in want}
     if got != want or cfg.altup.recycled != bool(alt["recycled"]):
         raise RuntimeError(f"program config {got} (recycled="
@@ -204,10 +193,10 @@ def prepare(cell, seed: int, seconds: float, *, warm: bool = True,
     from repro.serve.sampling import SamplingParams
 
     dep = cell.deployment
-    cfg = program_config(cell.config)
-    shape = models.shape_of(cell.config)
-    params = weights.make_params(shape, cfg.qk_norm,
-                                 cell.config["model"]["torch_dtype"], seed)
+    cfg = program_config(cell.family, cell.config)
+    shape = cell.family.shape(cell.config)
+    params = weights.make_params(cell.family.layout(cell.config),
+                                 shape.vocab, seed)
     weights.check_layout(params, jax.eval_shape(
         lambda: init_params(jax.random.PRNGKey(0), cfg)))
     docs = traffic.documents(cell.traffic, seed, shape.vocab)
@@ -366,7 +355,8 @@ def compare(sess: Session, done: List[tuple], *, control: bool = False):
                             stream=check.SAMPLED_STREAM)
     sess.eng = None
     gc.collect()
-    gaps = check.served_gaps(sess.params, sess.cell.config, picked,
+    cell = sess.cell
+    gaps = check.served_gaps(sess.params, cell.family, cell.config, picked,
                              control=control)
     numbers = {
         "max_logit_gap": max((float(g.max()) for g, _ in gaps if g.size),
@@ -380,7 +370,8 @@ def compare(sess: Session, done: List[tuple], *, control: bool = False):
         readings["control_max_logit_gap"] = max(
             (float(c.max()) for _, c in gaps if c.size), default=0.0)
     if samples(sess.cell):
-        nuc = check.served_nucleus(sess.params, sess.cell.config, picked_s)
+        nuc = check.served_nucleus(sess.params, cell.family, cell.config,
+                                   picked_s)
         numbers.update(check.sampled_numbers(nuc))
         readings["nucleus_excess_max"] = max(
             (float(e.max()) for e, *_ in nuc if e.size), default=None)

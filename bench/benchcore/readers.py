@@ -7,6 +7,7 @@ want of data.
 """
 from __future__ import annotations
 
+from collections import Counter
 from typing import Callable, Optional
 
 from benchcore import models
@@ -17,22 +18,27 @@ def traced_calls(run):
     return [s.call for s in run.steps if s.traced and s.call is not None]
 
 
-def kernel_roofline(run, kernel: str, cost: Callable[[dict], tuple],
+def kernel_roofline(run, kernel: str,
+                    cost: Callable[[dict, models.Layer], tuple],
                     applies: Callable[[dict], bool]) -> Optional[float]:
     """100 x (least time the traced calls of `kernel` could take, by the
     byte and FLOP model, at the peaks of the chip) / (their device time).
-    `cost(call)` gives one layer's (flops, bytes) for a fused call;
-    `applies(call)` says whether the kernel ran in it."""
+    `cost(call, layer)` gives one layer's (flops, bytes) for a fused call,
+    once for each kind of `models.Layer` the model has, counted as often
+    as the model has it; `applies(call)` says whether the kernel ran in
+    it."""
     if run.trace is None:
         return None
     secs, n = run.trace.kernel_seconds(kernel)
     if n == 0 or secs <= 0:
         return None
+    kinds = Counter(run.shape.layers)
     least = 0.0
     for c in traced_calls(run):
         if applies(c):
-            f, b = cost(c)
-            least += run.shape.n_layers * models.least_time(f, b, run.peaks)
+            for layer, count in kinds.items():
+                f, b = cost(c, layer)
+                least += count * models.least_time(f, b, run.peaks)
     return 100.0 * least / secs
 
 
@@ -45,9 +51,9 @@ def attn_roofline(run, kernel: str, paged: bool) -> Optional[float]:
     if bool(run.page) != paged:
         return None
 
-    def cost(c):
+    def cost(c, layer):
         return models.attn_kernel_cost(run.shape, fill_depths(c),
                                        T=run.max_len, kv_dtype=run.kv_dtype,
-                                       page=run.page)
+                                       page=run.page, layer=layer)
 
     return kernel_roofline(run, kernel, cost, lambda c: c["width"] == 1)

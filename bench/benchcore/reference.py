@@ -2,19 +2,23 @@
 float32 at the highest matmul precision. It imports nothing of the
 program and reads only the weights the benchmark made (`weights.py`).
 
-The model is a pre-norm decoder (Qwen3 / Granite 3 style: RMSNorm, GQA
-with rotary embeddings on the two halves of each head, optional per-head
-RMSNorm of queries and keys, SwiGLU MLP, embedding tied to the output
-head) widened with AltUp (Baykal et al. 2023, Alg. 1): the residual
-stream is K blocks of width d; layer i computes on block i % K, and
+What differs from family to family, the layers of the stack, is the
+configuration's model family's (`families/<family>.py` `segments`; the
+contract is in `family.py`); this file holds what every family shares,
+and the pieces (`rms_norm`, `rope`, `attention`) that families build
+their layers from. The stack is widened with AltUp (Baykal et al. 2023,
+Alg. 1): the residual stream is K blocks of width d; layer i, counted
+across segments, computes on block i % K, and
 
     x_hat  = P x                       (predict, K x K mixing)
     x_new  = x_hat + g * (L(x[j]) - x_hat[j])   (correct, j = i % K)
 
 The recycled variant repeats the d-wide embedding K times and sums the
 blocks before the head; the wide variant reads K*d-wide embedding rows
-and normalises the K*d concatenation before the head. Norm gains are
-applied as x * (1 + g), the layout the weights are stored in.
+and normalises the K*d concatenation before the head. The head is the
+embedding, tied, or an untied `lm_head` where the weights hold one.
+Norm gains are applied as x * (1 + g), the layout the weights are stored
+in.
 
 `precision="fp8"` is the control: every matmul weight rounded to
 float8 e4m3 with one scale per output channel, activations in bfloat16
@@ -32,8 +36,6 @@ import jax.numpy as jnp
 HI = jax.lax.Precision.HIGHEST
 FP8_MAX = 448.0
 Q_BLOCK = 512
-# per-head RMSNorm of queries and keys, by the source's model_type
-QK_NORM = {"qwen3": True, "granite": False}
 
 
 def _fp8(w, axis):
@@ -98,30 +100,9 @@ def attention(q, k, v, mm):
     return jnp.concatenate(outs, 0).reshape(L, H, dh)
 
 
-def layer(x, p, arch, mm):
-    """One width-d pre-norm layer with its residuals. x: (L, d) f32."""
-    eps = arch["rms_norm_eps"]
-    h = rms_norm(x, p["ln_attn"], eps)
-    a = p["attn"]
-    q = mm.ein("ld,dhk->lhk", h, mm.w(a["wq"], 0))
-    k = mm.ein("ld,dhk->lhk", h, mm.w(a["wk"], 0))
-    v = mm.ein("ld,dhk->lhk", h, mm.w(a["wv"], 0))
-    if arch["qk_norm"]:
-        q = rms_norm(q, a["q_norm"], eps)
-        k = rms_norm(k, a["k_norm"], eps)
-    q, k = rope(q, arch["rope_theta"]), rope(k, arch["rope_theta"])
-    o = attention(q, k, v, mm)
-    x = x + mm.ein("lhk,hkd->ld", o, mm.w(a["wo"], (0, 1)))
-    h = rms_norm(x, p["ln_ffn"], eps)
-    f = p["ffn"]
-    u = jax.nn.silu(mm.ein("ld,df->lf", h, mm.w(f["w1"], 0))) \
-        * mm.ein("ld,df->lf", h, mm.w(f["w3"], 0))
-    return x + mm.ein("lf,fd->ld", u, mm.w(f["w2"], 0))
-
-
-def final_hidden(params, tokens, arch, precision="f32"):
+def final_hidden(params, tokens, arch, family, precision="f32"):
     """(L, width) f32 normalised output of the stack for `tokens` (L,),
-    and the (possibly rounded) embedding table the head multiplies."""
+    and the (possibly rounded) head as (padded vocab, width)."""
     mm = _Math(precision)
     K, d = arch["K"], arch["d_model"]
     emb = mm.w(params["embed"], 1)                      # (Vp, width)
@@ -129,33 +110,37 @@ def final_hidden(params, tokens, arch, precision="f32"):
     L = tokens.shape[0]
     x = jnp.broadcast_to(x[:, None, :], (L, K, d)) if arch["recycled"] \
         else x.reshape(L, K, d)
-    seg = params["seg0"]
+    first = 0                           # global index of a segment's first
+    for seg, n, layer in family.segments(params, arch):
 
-    def body(x, per_layer):
-        p, i = per_layer
-        j = i % K
-        onehot = (jnp.arange(K) == j).astype(jnp.float32)
-        x_j = jnp.einsum("k,lkd->ld", onehot, x, precision=HI)
-        out = layer(x_j, p, arch, mm)
-        pm = p["altup_p"].astype(jnp.float32)
-        x_hat = jnp.einsum("ij,ljd->lid", pm, x, precision=HI)
-        hat_j = jnp.einsum("k,lkd->ld", onehot, x_hat, precision=HI)
-        g = p["altup_g"].astype(jnp.float32)
-        return x_hat + g[None, :, None] * (out - hat_j)[:, None, :], None
+        def body(x, per_layer, layer=layer):
+            p, i = per_layer
+            j = i % K
+            onehot = (jnp.arange(K) == j).astype(jnp.float32)
+            x_j = jnp.einsum("k,lkd->ld", onehot, x, precision=HI)
+            out = layer(x_j, p, arch, mm)
+            pm = p["altup_p"].astype(jnp.float32)
+            x_hat = jnp.einsum("ij,ljd->lid", pm, x, precision=HI)
+            hat_j = jnp.einsum("k,lkd->ld", onehot, x_hat, precision=HI)
+            g = p["altup_g"].astype(jnp.float32)
+            return x_hat + g[None, :, None] * (out - hat_j)[:, None, :], None
 
-    x, _ = jax.lax.scan(body, x, (seg, jnp.arange(arch["n_layers"])))
+        x, _ = jax.lax.scan(body, x, (seg, jnp.arange(first, first + n)))
+        first += n
     x = x.sum(1) if arch["recycled"] else x.reshape(L, K * d)
-    return rms_norm(x, params["final_norm"], arch["rms_norm_eps"]), emb, mm
+    head = emb if "lm_head" not in params \
+        else mm.w(params["lm_head"], 0).T              # (width, Vp) stored
+    return rms_norm(x, params["final_norm"], arch["rms_norm_eps"]), head, mm
 
 
-def logits(params, tokens, arch, precision="f32"):
+def logits(params, tokens, arch, family, precision="f32"):
     """(L, vocab) f32 logits of the whole sequence `tokens` (L,)."""
-    x, emb, mm = final_hidden(params, tokens, arch, precision)
+    x, emb, mm = final_hidden(params, tokens, arch, family, precision)
     return mm.ein("lw,vw->lv", x, emb)[:, :arch["vocab"]]
 
 
-@partial(jax.jit, static_argnames=("arch_items", "control"))
-def gaps(params, tokens, targets, arch_items, control=False):
+@partial(jax.jit, static_argnames=("arch_items", "family", "control"))
+def gaps(params, tokens, targets, arch_items, family, control=False):
     """Per position of `tokens` (padded, causal, so padding at the end
     changes nothing before it): the reference's best logit minus its
     logit of `targets` (the served token, -1 where none is compared); and
@@ -164,9 +149,9 @@ def gaps(params, tokens, targets, arch_items, control=False):
     head runs in blocks of rows, so no (L, vocab) array is held."""
     arch = dict(arch_items)
     V = arch["vocab"]
-    x, emb, mm = final_hidden(params, tokens, arch, "f32")
+    x, emb, mm = final_hidden(params, tokens, arch, family, "f32")
     if control:
-        xl, embl, mml = final_hidden(params, tokens, arch, "fp8")
+        xl, embl, mml = final_hidden(params, tokens, arch, family, "fp8")
     served, ctl = [], []
     for s0 in range(0, tokens.shape[0], Q_BLOCK):
         ref = mm.ein("lw,vw->lv", x[s0:s0 + Q_BLOCK], emb)[:, :V]
@@ -187,8 +172,9 @@ def gaps(params, tokens, targets, arch_items, control=False):
 NUCLEUS_ROWS = 128      # rows of the head per step of the sampled check
 
 
-@partial(jax.jit, static_argnames=("arch_items",))
-def nucleus(params, tokens, rows, served, temperature, top_p, arch_items):
+@partial(jax.jit, static_argnames=("arch_items", "family"))
+def nucleus(params, tokens, rows, served, temperature, top_p, arch_items,
+            family):
     """The sampled check's readings of one request that sampled at
     (`temperature`, `top_p`): `tokens` is its prompt and served tokens
     (padded at the end), `served[i]` the token served after position
@@ -209,7 +195,7 @@ def nucleus(params, tokens, rows, served, temperature, top_p, arch_items):
     The head runs in blocks of rows, so no (L, vocab) array is held."""
     arch = dict(arch_items)
     V = arch["vocab"]
-    x, emb, mm = final_hidden(params, tokens, arch, "f32")
+    x, emb, mm = final_hidden(params, tokens, arch, family, "f32")
     R, w = rows.shape[0], x.shape[1]
     nb = -(-R // NUCLEUS_ROWS)
     pad = nb * NUCLEUS_ROWS - R
@@ -242,19 +228,3 @@ def nucleus(params, tokens, rows, served, temperature, top_p, arch_items):
 
     outs = jax.lax.map(block, (xb, tb))
     return tuple(o.reshape(-1)[:R] for o in outs)
-
-
-def arch_of(config: dict) -> tuple:
-    """The reference's hashable description of a configuration file."""
-    m = config["model"]
-    d = int(m["hidden_size"])
-    return tuple(sorted({
-        "K": int(config["altup"]["K"]),
-        "recycled": bool(config["altup"]["recycled"]),
-        "d_model": d,
-        "vocab": int(m["vocab_size"]),
-        "n_layers": int(m["num_hidden_layers"]),
-        "rms_norm_eps": float(m["rms_norm_eps"]),
-        "rope_theta": float(m["rope_theta"]),
-        "qk_norm": QK_NORM[m["model_type"]],
-    }.items()))

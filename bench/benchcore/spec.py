@@ -9,7 +9,11 @@ resolving applies) and `traffic/<mix>.json`. An optional
 the deployment (`"deployment"`) and of the traffic parameters
 (`"traffic"`), and the limits of the correctness comparison
 (`"limits"`). Per-layer metrics are readers `metrics/<metric>.py`.
-A cell is added by adding data; no file here changes.
+The configuration file names its model family (`"family"`), whose file
+`families/<family>.py` holds what the harness knows of the model's
+structure (the contract is in `family.py`). A cell is added by adding
+data, and a model of a new family by adding its family file; no file
+here changes.
 """
 from __future__ import annotations
 
@@ -18,6 +22,7 @@ import importlib.util
 import json
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
@@ -49,6 +54,7 @@ class Cell:
     chips: int
     config_name: str
     config: dict                 # the configuration file, deployment merged
+    family: ModuleType           # families/<config["family"]>.py
     traffic_name: str
     traffic: dict                # the mix file, cell overrides merged
     limits: Dict[str, float]
@@ -61,14 +67,26 @@ class Cell:
         return self.config["deployment"]
 
 
-def load_reader(name: str, bench_dir: Path = BENCH_DIR) -> Callable:
-    """`metrics/<name>.py`'s `read(run) -> float | None`."""
-    path = bench_dir / "metrics" / f"{name}.py"
+def _load_file(path: Path, module_name: str):
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path)
+        module_name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name: str, bench_dir: Path = BENCH_DIR) -> Callable:
+    """`metrics/<name>.py`'s `read(run) -> float | None`."""
+    return _load_file(bench_dir / "metrics" / f"{name}.py",
+                      f"bench_metric_{name}").read
+
+
+def load_family(name: str, bench_dir: Path = BENCH_DIR) -> ModuleType:
+    """`families/<name>.py`, the model family (contract: `family.py`)."""
+    path = Path(bench_dir) / "families" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no model family {name!r}: {path}")
+    return _load_file(path, f"bench_family_{name}")
 
 
 def resolve(workload: str, benchmark: Optional[dict] = None,
@@ -80,6 +98,9 @@ def resolve(workload: str, benchmark: Optional[dict] = None,
     w = cells[workload]
     cfg_entry = {c["name"]: c for c in bm["configs"]}[w["config"]]
     config = json.loads((root / cfg_entry["file"]).read_text())
+    if "family" not in config:
+        raise KeyError(f"configuration {cfg_entry['file']} names no model "
+                       f"family (\"family\")")
     # `model` holds the source's numbers; where the program runs others
     # (`departures`), those are what it is checked against and the
     # reference computes with
@@ -95,6 +116,7 @@ def resolve(workload: str, benchmark: Optional[dict] = None,
     return Cell(
         name=workload, chips=int(w["chips"]),
         config_name=w["config"], config=config,
+        family=load_family(config["family"], bench_dir),
         traffic_name=w["traffic"], traffic=traffic,
         limits=dict(over.get("limits", {})),
         end_to_end=[m for m in bm["end_to_end"] if _applies(m, workload)],

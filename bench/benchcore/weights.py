@@ -2,11 +2,11 @@
 the layout and types the served model takes them.
 
 The benchmark makes the weights itself, so the plain reference
-(`reference.py`) reads nothing the program made. The layout is that of a
-dense decoder with AltUp: `embed`, `final_norm` and one stacked segment
-`seg0` of per-layer leaves. `check_layout` compares it with the program's
-own parameter shapes, so a change of layout stops the run at set-up
-instead of feeding the program arrays it does not expect.
+(`reference.py`) reads nothing the program made. The layout, every leaf
+with its shape, type and kind in the order that seeds it, is the model
+family's (`families/<family>.py` `layout`). `check_layout` compares it
+with the program's own parameter shapes, so a change of layout stops the
+run at set-up instead of feeding the program arrays it does not expect.
 
 Values: every matmul weight ~ N(0, 1/fan_in); norm gains stored as
 scale - 1 ~ N(0, 0.1^2) (the program applies x * (1 + g)); AltUp's
@@ -26,34 +26,6 @@ VOCAB_PAD = 256
 
 def padded_vocab(v: int) -> int:
     return -(-v // VOCAB_PAD) * VOCAB_PAD
-
-
-def layout(shape, qk_norm: bool, dtype: str) -> Dict[str, tuple]:
-    """{path: (shape, dtype name, kind)} of every leaf."""
-    s = shape
-    L, d, H, Hk, dh, F, K = (s.n_layers, s.d_model, s.n_heads,
-                             s.n_kv_heads, s.head_dim, s.d_ff, s.altup_k)
-    width = d if (K == 1 or s.recycled) else K * d
-    leaves = {
-        "embed": ((padded_vocab(s.vocab), width), dtype, "embed"),
-        "final_norm": ((width,), dtype, "norm"),
-        "seg0/ln_attn": ((L, d), dtype, "norm"),
-        "seg0/attn/wq": ((L, d, H, dh), dtype, d),
-        "seg0/attn/wk": ((L, d, Hk, dh), dtype, d),
-        "seg0/attn/wv": ((L, d, Hk, dh), dtype, d),
-        "seg0/attn/wo": ((L, H, dh, d), dtype, H * dh),
-        "seg0/ln_ffn": ((L, d), dtype, "norm"),
-        "seg0/ffn/w1": ((L, d, F), dtype, d),
-        "seg0/ffn/w3": ((L, d, F), dtype, d),
-        "seg0/ffn/w2": ((L, F, d), dtype, F),
-    }
-    if qk_norm:
-        leaves["seg0/attn/q_norm"] = ((L, dh), dtype, "norm")
-        leaves["seg0/attn/k_norm"] = ((L, dh), dtype, "norm")
-    if K > 1:
-        leaves["seg0/altup_p"] = ((L, K, K), "float32", "altup_p")
-        leaves["seg0/altup_g"] = ((L, K), "float32", "altup_g")
-    return leaves
 
 
 def _nest(flat: Dict[str, jax.Array]) -> dict:
@@ -82,25 +54,26 @@ def _leaf(key, shp, dtype, kind, vocab):
     return x.astype(dtype)
 
 
-def maker(shape, qk_norm: bool, dtype: str):
-    """A jitted fn(seed_lo, seed_hi) -> params; one compile for all seeds."""
-    leaves = layout(shape, qk_norm, dtype)
+def maker(leaves: Dict[str, tuple], vocab: int):
+    """A jitted fn(seed_lo, seed_hi) -> params of the layout `leaves`
+    ({path: (shape, dtype name, kind)}); one compile for all seeds. Leaf
+    i draws from fold_in(base, i); embedding rows past `vocab` are 0."""
 
     def make(lo, hi):
         base = jax.random.fold_in(jax.random.fold_in(
             jax.random.key(0), lo), hi)
         flat = {path: _leaf(jax.random.fold_in(base, i), shp, dt, kind,
-                            shape.vocab)
+                            vocab)
                 for i, (path, (shp, dt, kind)) in enumerate(leaves.items())}
         return _nest(flat)
 
     return jax.jit(make)
 
 
-def make_params(shape, qk_norm: bool, dtype: str, seed: int):
+def make_params(leaves: Dict[str, tuple], vocab: int, seed: int):
     s = int(seed) % (1 << 64)
-    return maker(shape, qk_norm, dtype)(jnp.uint32(s & 0xFFFFFFFF),
-                                 jnp.uint32(s >> 32))
+    return maker(leaves, vocab)(jnp.uint32(s & 0xFFFFFFFF),
+                                jnp.uint32(s >> 32))
 
 
 def check_layout(params, program_shapes) -> None:

@@ -7,7 +7,7 @@ from benchcore.readers import kernel_roofline
 
 
 def read(run):
-    def cost(c):
+    def cost(c, _layer):
         return models.altup_kernel_cost(run.shape,
                                         len(c["pos"]) * c["width"])
 

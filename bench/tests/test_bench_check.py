@@ -1,7 +1,9 @@
 """The comparison that decides `correct`: it passes the program, and it
 fails the control and each fault a served cell can have. Runs the whole
 harness on the CPU at a tiny size (the program's qwen3 SMOKE preset with
-AltUp K=2), with the look for a chip skipped."""
+AltUp K=2), with the look for a chip skipped. A model family is found by
+its file alone: a test family, cut by `reduced`, runs and is judged by
+its own reference."""
 import json
 import shutil
 import sys
@@ -21,22 +23,43 @@ from benchcore import check, faults, traffic  # noqa: E402
 TINY = BENCH / "tests" / "data" / "tiny"
 CELL = "tiny-qwen3.tinychat"
 PAGED = "tiny-qwen3.tinydoc-paged"
+SPLIT = "tiny-split.tinychat"
+# the test family with its reference's per-head norm of queries and keys
+# left out, while the program keeps it
+PLANTED_FAMILY = """
+from pathlib import Path
+from benchcore import spec
+_split = spec.load_family("tiny_split",
+                          Path(__file__).resolve().parents[1])
+program_config, layout, arch, shape = (_split.program_config, _split.layout,
+                                       _split.arch, _split.shape)
+def segments(params, arch):
+    return [(seg, n, lambda x, p, a, mm, f=f: f(x, p, {**a, "qk_norm": False},
+                                                mm))
+            for seg, n, f in _split.segments(params, arch)]
+"""
 
 
 @pytest.fixture(scope="module")
 def tiny(tmp_path_factory):
     root = tmp_path_factory.mktemp("tiny")
-    for d in ("configs", "traffic", "cells"):
+    for d in ("configs", "traffic", "cells", "families"):
         shutil.copytree(TINY / d, root / d)
+    for f in (BENCH / "families").glob("*.py"):
+        shutil.copy(f, root / "families" / f.name)
     shutil.copytree(BENCH / "metrics", root / "metrics")
     shutil.copy(BENCH / "peaks.json", root / "peaks.json")
     bm = json.loads((ROOT / "BENCHMARK.json").read_text())
     bm = {"configs": [{"name": "tiny-qwen3",
-                       "file": "configs/tiny-qwen3.json"}],
+                       "file": "configs/tiny-qwen3.json"},
+                      {"name": "tiny-split",
+                       "file": "configs/tiny-split.json"}],
           "workloads": [{"name": CELL, "config": "tiny-qwen3",
                          "traffic": "tinychat", "chips": 1},
                         {"name": PAGED, "config": "tiny-qwen3",
-                         "traffic": "tinydoc", "chips": 1}],
+                         "traffic": "tinydoc", "chips": 1},
+                        {"name": SPLIT, "config": "tiny-split",
+                         "traffic": "tinychat", "chips": 1}],
           "end_to_end": bm["end_to_end"],
           "per_layer": [m for m in bm["per_layer"] if "workloads" not in m]}
     return root, bm
@@ -84,6 +107,42 @@ def test_program_is_correct(tiny, cell):
     assert {"itl_p50_ms", "output_tok_per_s", "setup_s"} <= set(r["metrics"])
 
 
+def test_family_found_by_its_file_alone(tiny):
+    """A configuration of a family that `benchcore` does not know, cut
+    to 2 layers by `reduced`, resolves to its family file, and the run
+    and its check take the family's layout, program and reference."""
+    from benchcore import spec
+    root, bm = tiny
+    cell = spec.resolve(SPLIT, bm, root, root)
+    assert Path(cell.family.__file__) == root / "families" / "tiny_split.py"
+    r = run(tiny, SPLIT)
+    assert r["correct"] is True
+    assert r["compared"]["max_logit_gap"]["value"] <= 1e-4
+    assert r["compared"]["compared_tokens"]["value"] >= 20
+
+
+def test_family_reference_is_the_one_that_runs(tiny):
+    """The same configuration under a planted family whose reference
+    leaves out the norm of queries and keys: the sound program is then
+    judged not correct, so the check ran the family's own reference."""
+    root, bm = tiny
+    (root / "families" / "tiny_noqk.py").write_text(PLANTED_FAMILY)
+    config = json.loads((root / "configs" / "tiny-split.json").read_text())
+    config.update(name="tiny-noqk", family="tiny_noqk")
+    (root / "configs" / "tiny-noqk.json").write_text(json.dumps(config))
+    shutil.copy(root / "cells" / f"{SPLIT}.json",
+                root / "cells" / "tiny-noqk.tinychat.json")
+    bm = {**bm, "configs": bm["configs"] + [
+        {"name": "tiny-noqk", "file": "configs/tiny-noqk.json"}],
+        "workloads": bm["workloads"] + [
+        {"name": "tiny-noqk.tinychat", "config": "tiny-noqk",
+         "traffic": "tinychat", "chips": 1}]}
+    r = run((root, bm), "tiny-noqk.tinychat")
+    assert r["correct"] is False
+    gap = r["compared"]["max_logit_gap"]
+    assert gap["value"] > gap["limit"]
+
+
 @pytest.mark.parametrize("fault,fails", [
     (token_altered, "max_logit_gap"),
     (state_unchanged, "max_logit_gap"),
@@ -104,26 +163,28 @@ def test_control_is_not_correct(tiny):
     ranks first tokens the float32 reference does not: its widest gap
     exceeds the cell's limit, where the reference's own choice reads 0."""
     import jax.numpy as jnp
-    from benchcore import models, reference, weights
+    from benchcore import reference, spec, weights
     root, _ = tiny
     config = json.loads((root / "configs" / "tiny-qwen3.json").read_text())
     limit = json.loads((root / "cells" / f"{CELL}.json").read_text())[
         "limits"]["max_logit_gap"]
-    shape = models.shape_of(config)
-    params = weights.make_params(shape, True, "float32", 11)
-    arch = reference.arch_of(config)
+    dense = spec.load_family("dense_decoder", root)
+    shape = dense.shape(config)
+    params = weights.make_params(dense.layout(config), shape.vocab, 11)
+    arch = dense.arch(config)
     rng = traffic.rng_for(11, 0)
     picked = []
     for n in (24, 40, 33):
         seq = rng.integers(0, shape.vocab, n).astype(np.int32)
         for _ in range(16):                       # greedy by the reference
-            lg = reference.logits(params, jnp.asarray(seq), dict(arch))
+            lg = reference.logits(params, jnp.asarray(seq), dict(arch),
+                                  dense)
             seq = np.append(seq, np.int32(np.argmax(np.asarray(lg[-1]))))
         req = traffic.Request(index=0, due_s=0.0, prompt=seq[:n],
                               max_new=16, greedy=True, temperature=0.0,
                               top_p=1.0, sample_seed=0)
         picked.append((req, tuple(int(t) for t in seq[n:])))
-    gaps = check.served_gaps(params, config, picked, control=True)
+    gaps = check.served_gaps(params, dense, config, picked, control=True)
     served = max(float(g.max()) for g, _ in gaps)
     control = max(float(c.max()) for _, c in gaps)
     assert served <= 1e-5 < limit < control

@@ -17,7 +17,7 @@ ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(ROOT / "src"))
 
-from benchcore import driver, engine_trace, models, spec  # noqa: E402
+from benchcore import driver, engine_trace, spec  # noqa: E402
 from benchcore import trace as tr  # noqa: E402
 
 DATA = BENCH / "tests" / "data"
@@ -38,7 +38,8 @@ def _record(red, stats=None, calls=()):
     cfg = json.loads((BENCH / "configs" / "qwen3-0.6b-altup2.json")
                      .read_text())
     steps = [driver.Step(0.0, 0.04, 8, traced=True, call=c) for c in calls]
-    return driver.RunRecord(shape=models.shape_of(cfg),
+    shape = spec.load_family(cfg["family"], BENCH).shape(cfg)
+    return driver.RunRecord(shape=shape,
                             peaks=driver.load_peaks("TPU v5 lite"),
                             kv_dtype="bf16", max_len=4096, page=0,
                             steps=steps, stats=stats or {}, trace=red)
@@ -142,7 +143,8 @@ def test_counters_reported_by_a_traced_tiny_run(tmp_path):
     tiny = DATA / "tiny"
     for d in ("configs", "traffic", "cells"):
         shutil.copytree(tiny / d, tmp_path / d)
-    shutil.copytree(BENCH / "metrics", tmp_path / "metrics")
+    for d in ("metrics", "families"):
+        shutil.copytree(BENCH / d, tmp_path / d)
     shutil.copy(BENCH / "peaks.json", tmp_path / "peaks.json")
     full = json.loads((ROOT / "BENCHMARK.json").read_text())
     cell = "tiny-qwen3.tinydoc-paged"

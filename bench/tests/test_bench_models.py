@@ -1,5 +1,6 @@
 """The benchmark's copies of the program's byte models stay equal to the
 originals at the cells' shapes, and the FLOP arithmetic is consistent."""
+import dataclasses
 import json
 import sys
 from pathlib import Path
@@ -23,8 +24,8 @@ LENGTHS = [[0, 1, 17, 100, 999, 1537, 2048, 4095],
 def test_kv_byte_models_equal_the_programs(cell):
     from repro.roofline import analysis
     c = spec.resolve(cell)
-    cfg = driver.program_config(c.config)
-    s = models.shape_of(c.config)
+    cfg = driver.program_config(c.family, c.config)
+    s = c.family.shape(c.config)
     T = c.deployment["max_len"]
     kv = c.deployment["kv_cache_dtype"]
     for ln in LENGTHS:
@@ -39,8 +40,13 @@ def test_kv_byte_models_equal_the_programs(cell):
         assert ours == theirs
 
 
+def _shape(cell):
+    c = spec.resolve(cell)
+    return c.family.shape(c.config)
+
+
 def test_span_flops_is_the_sum_of_tokens():
-    s = models.shape_of(spec.resolve(CELLS[0]).config)
+    s = _shape(CELLS[0])
     one = [models.span_flops(s, p, 1) for p in range(40, 48)]
     assert models.span_flops(s, 40, 8) == pytest.approx(sum(one), rel=1e-12)
     # matmuls dominate a short context: ~2 FLOPs per weight
@@ -49,12 +55,13 @@ def test_span_flops_is_the_sum_of_tokens():
 
 
 def test_kernel_costs_positive_and_memory_bound():
-    s = models.shape_of(spec.resolve(CELLS[0]).config)
+    s = _shape(CELLS[0])
     peaks = driver.load_peaks("TPU v5 lite")
     f, b = models.attn_kernel_cost(s, [100] * 8, T=4096)
+    layer = s.layers[0]
     assert b == models.decode_kv_bytes(
-        models.Shape(**{**s.__dict__, "windows": (0,)}), [100] * 8,
-        T=4096) + 2 * 8 * s.n_heads * s.head_dim * 2 + 4 * 8
+        dataclasses.replace(s, layers=(layer,)), [100] * 8,
+        T=4096) + 8 * layer.attn_io * 2 + 4 * 8
     assert models.least_time(f, b, peaks) == b / peaks["hbm_bytes_per_s"]
     fp, bp = models.attn_kernel_cost(s, [100] * 8, T=4096, page=16)
     assert bp > b and fp == f              # whole pages and the table

@@ -6,6 +6,7 @@ import shutil
 import sys
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 BENCH = Path(__file__).resolve().parents[1]
@@ -144,14 +145,26 @@ def test_unknown_device_kind_raises():
         driver.load_peaks("TPU v99 imaginary")
 
 
+def _sources():
+    return {p: p.read_bytes() for pat in ("*.json", "*.py")
+            for p in BENCH.rglob(pat)}
+
+
 def test_cell_added_from_data_alone(tmp_path):
     """A new workloads entry naming an existing config and mix, with its
     own traffic parameters and a layout override in a cell file of its
     own, resolves to its config, generator and readers and generates its
-    requests; no file of the benchmark changes."""
-    for d in ("configs", "traffic", "metrics", "cells"):
+    requests; and a configuration of a model family the harness has not
+    seen resolves to the family's own file. No file of the benchmark
+    changes."""
+    for d in ("configs", "traffic", "metrics", "cells", "families"):
         shutil.copytree(BENCH / d, tmp_path / "bench" / d)
-    before = {p: p.read_bytes() for p in BENCH.rglob("*.json")}
+    tiny = BENCH / "tests" / "data" / "tiny"
+    shutil.copy(tiny / "configs" / "tiny-split.json",
+                tmp_path / "bench" / "configs")
+    shutil.copy(tiny / "families" / "tiny_split.py",
+                tmp_path / "bench" / "families")
+    before = _sources()
     bm = json.loads(json.dumps(BM))
     name = "qwen3-0.6b-altup2.chat-paged-fast"
     bm["workloads"].append({"name": name, "config": "qwen3-0.6b-altup2",
@@ -160,7 +173,7 @@ def test_cell_added_from_data_alone(tmp_path):
         "deployment": {"paged": True, "page_size": 32},
         "traffic": {"arrivals": {"rate_per_s": 3.0}},
         "limits": {"max_logit_gap": 1.0, "compared_tokens": 100}}))
-    cell = spec.resolve(name, bm, tmp_path / "bench", ROOT)
+    cell = spec.resolve(name, bm, tmp_path / "bench", tmp_path)
     assert cell.deployment["paged"] is True
     assert cell.deployment["page_size"] == 32
     assert cell.deployment["n_slots"] == 8          # the config's own
@@ -169,6 +182,42 @@ def test_cell_added_from_data_alone(tmp_path):
     assert {m["name"] for m in cell.per_layer} >= {"engine_step_ms",
                                                    "step_device_ms"}
     assert all(callable(r) for r in cell.readers.values())
+    assert cell.family.shape(cell.config).n_layers == 28
     reqs = traffic.generate(cell.traffic, 5, 20.0, 151936)
     assert 40 <= len(reqs) <= 80
-    assert {p: p.read_bytes() for p in BENCH.rglob("*.json")} == before
+
+    bm["configs"].append({"name": "tiny-split", "source": "test",
+                          "file": "bench/configs/tiny-split.json",
+                          "reduced": ["num_hidden_layers"], "why": "test"})
+    bm["workloads"].append({"name": "tiny-split.chat", "config": "tiny-split",
+                            "traffic": "chat", "chips": 1, "why": "test"})
+    cell = spec.resolve("tiny-split.chat", bm, tmp_path / "bench", tmp_path)
+    assert Path(cell.family.__file__) == \
+        tmp_path / "bench" / "families" / "tiny_split.py"
+    params = {"seg0": {"w": np.arange(6.0).reshape(2, 3)}}
+    split = cell.family.segments(params, dict(cell.family.arch(cell.config)))
+    assert [n for _, n, _ in split] == [1, 1]
+    assert cell.family.shape(cell.config).n_layers == 2
+    assert _sources() == before
+
+
+@pytest.mark.parametrize("family", [None, "no_such_family"],
+                         ids=["no_family_key", "missing_module"])
+def test_config_without_its_family_raises(tmp_path, family):
+    """A configuration names its family, and the family's file exists;
+    there is no default family."""
+    shutil.copytree(BENCH / "families", tmp_path / "families")
+    config = json.loads((BENCH / "configs" / "qwen3-0.6b-altup2.json")
+                        .read_text())
+    if family is None:
+        del config["family"]
+    else:
+        config["family"] = family
+    (tmp_path / "c.json").write_text(json.dumps(config))
+    bm = {"configs": [{"name": "c", "file": "c.json"}],
+          "workloads": [{"name": "c.chat", "config": "c", "traffic": "chat",
+                         "chips": 1}],
+          "end_to_end": [], "per_layer": []}
+    shutil.copytree(BENCH / "traffic", tmp_path / "traffic")
+    with pytest.raises((KeyError, FileNotFoundError), match="family"):
+        spec.resolve("c.chat", bm, tmp_path, tmp_path)
